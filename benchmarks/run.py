@@ -21,6 +21,8 @@ def main() -> None:
                     help="tiny serving trace (CI-sized)")
     args = ap.parse_args()
 
+    from repro import compat
+    compat.enable_compilation_cache()
     from benchmarks import (bench_algorithm, bench_ivim_packed, bench_kernels,
                             bench_latency_model, bench_roofline,
                             bench_schedule, bench_serving)

@@ -13,11 +13,13 @@
 import jax
 import numpy as np
 
+from repro import compat
 from repro.ivim import data as ivim_data, model as ivim_model
 from repro.ivim import train as ivim_train
 
 
 def main() -> None:
+    compat.enable_compilation_cache()
     # Phase 1: synthetic scenario (SNR 20) + uncertainty requirements
     ds = ivim_data.make_dataset(ivim_data.SyntheticConfig(
         n_voxels=4000, snr=20.0, seed=0))

@@ -1,8 +1,9 @@
 """The paper's technique at LM scale: mask-based Bayesian *serving* with
-per-token uncertainty, on any assigned architecture (reduced config).
+per-token uncertainty, on any assigned architecture (reduced config, or
+its published widths with ``--full``).
 
     PYTHONPATH=src python examples/serve_uncertainty_lm.py \
-        [--arch qwen2-1.5b] [--tokens 12] [--server] \
+        [--arch qwen2-1.5b] [--full] [--tokens 12] [--server] \
         [--trace-out trace.jsonl] [--metrics-out metrics.prom]
 
 Every request is evaluated under N fixed Masksembles masks (no runtime RNG);
@@ -17,6 +18,11 @@ continuous-batching server instead — an admission queue feeding a
 prints the serving metrics (tokens/s, latency percentiles, slot occupancy).
 Both paths produce identical tokens and uncertainties; the server is how
 the batch-level mask schedule amortizes over live traffic.
+
+``--full`` serves the registry config at its published widths (bf16,
+seeded random weights, N masks on) instead of the reduced smoke config —
+qwen2-1.5b needs about 3.1 GB of device memory for its weights, so run it
+on the chip.
 
 ``--scan`` (with ``--server``) additionally submits a synthetic IVIM scan
 volume into the SAME pool as a voxel-chunk work item (``submit_scan``): one
@@ -48,6 +54,7 @@ import argparse
 import jax
 import numpy as np
 
+from repro import compat
 from repro.configs import registry
 from repro.models import build_model
 from repro.serving import (BayesianLMServer, ServeConfig, ServerConfig,
@@ -69,6 +76,9 @@ def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen2-1.5b",
                     choices=list(registry.ARCH_IDS))
+    ap.add_argument("--full", action="store_true",
+                    help="published widths (bf16) instead of the reduced "
+                         "smoke config")
     ap.add_argument("--tokens", type=int, default=12)
     ap.add_argument("--n-masks", type=int, default=4)
     ap.add_argument("--threshold", type=float, default=0.35)
@@ -108,12 +118,16 @@ def main() -> None:
         raise SystemExit("--trace-out/--metrics-out need --server (the "
                          "one-shot engine has no request lifecycle)")
 
-    cfg = registry.smoke_config(args.arch, mask_samples=args.n_masks)
+    compat.enable_compilation_cache()
+    make_cfg = registry.get_config if args.full else registry.smoke_config
+    cfg = make_cfg(args.arch, mask_samples=args.n_masks)
     if not cfg.has_decode:
         raise SystemExit(f"{args.arch} is encoder-only; pick a decoder arch")
     model = build_model(cfg)
     params = model.init(jax.random.PRNGKey(0))
-    print(f"arch={args.arch} (reduced), N={args.n_masks} fixed masks")
+    print(f"arch={args.arch} "
+          f"({'published widths' if args.full else 'reduced'}), "
+          f"N={args.n_masks} fixed masks")
 
     if args.server:
         prompts = jax.random.randint(jax.random.PRNGKey(1),

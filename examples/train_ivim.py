@@ -6,6 +6,7 @@ Figs. 6-7 (RMSE + uncertainty vs SNR) with the Phase-2 requirement gate.
 
 import argparse
 
+from repro import compat
 from repro.ivim import evaluate as E, model as M, train as T
 
 
@@ -17,6 +18,7 @@ def main() -> None:
     ap.add_argument("--dense-protocol", action="store_true",
                     help="use the 104-b-value research protocol")
     args = ap.parse_args()
+    compat.enable_compilation_cache()
 
     from repro.ivim import physics
     b_values = (physics.DENSE_B_VALUES if args.dense_protocol

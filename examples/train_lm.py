@@ -16,6 +16,7 @@ import dataclasses
 
 import jax.numpy as jnp
 
+from repro import compat
 from repro.configs import registry
 from repro.data import LMDataConfig
 from repro.models import build_model
@@ -35,6 +36,7 @@ def main() -> None:
     ap.add_argument("--grad-accum", type=int, default=2)
     ap.add_argument("--ckpt-dir", default="/tmp/repro_lm_ckpt")
     args = ap.parse_args()
+    compat.enable_compilation_cache()
 
     heads = max(4, args.d_model // 32)
     cfg = registry.smoke_config(

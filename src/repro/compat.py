@@ -1,28 +1,22 @@
-"""Version-portability layer: the single choke-point for drifted JAX APIs.
+"""The single choke-point for JAX's drifted APIs, written for jax 0.9.0.
 
-The repo targets "any JAX >= 0.4.35 (first ``jax.make_mesh``), TPU or CPU".
-Every API that has moved, been renamed, or grown/lost keyword arguments
-between that floor and current JAX is wrapped here, and **no other module
-under src/repro/ may touch the drifted spellings directly** (ci.sh greps
-for violations):
+The mesh, shard_map and tree spellings below have moved between JAX
+releases. Every other module under src/repro/ goes through the names here
+(the ``repro.analysis`` compat-drift rule rejects the direct spellings), so
+the next move is absorbed in one file:
 
-  =====================  ==========================  =======================
-  symbol                 new-JAX home                old-JAX fallback
-  =====================  ==========================  =======================
-  ``shard_map``          ``jax.shard_map``           ``jax.experimental.
-                         (``check_vma=``)            shard_map`` (``check_rep=``)
-  ``set_mesh``           ``jax.sharding.set_mesh``   process-wide ``with mesh:``
-                                                     resource env (ExitStack)
-  ``use_mesh``           ``jax.sharding.use_mesh``   ``with mesh:``
-  ``make_mesh``          ``jax.make_mesh(...,        ``jax.make_mesh`` without
-                         axis_types=...)``           it / ``mesh_utils``
-  ``AxisType``           ``jax.sharding.AxisType``   ``None`` (meshes are
-                                                     implicitly Auto)
-  tree utilities         ``jax.tree.*`` /            ``jax.tree_util.*``
-                         ``jax.tree_util.*``
-  =====================  ==========================  =======================
+  =====================  ==============================================
+  symbol                 jax 0.9.0 home
+  =====================  ==============================================
+  ``shard_map``          ``jax.shard_map`` (``check_vma=``)
+  ``set_mesh``           ``jax.sharding.set_mesh`` (process default)
+  ``use_mesh``           ``jax.sharding.set_mesh`` as a context manager
+  ``make_mesh``          ``jax.make_mesh(..., axis_types=Auto...)``
+  ``AxisType``           ``jax.sharding.AxisType``
+  tree utilities         ``jax.tree.*`` / ``jax.tree_util.*``
+  =====================  ==============================================
 
-Kernel backend selection lives here too: the four ``kernels/*/ops.py``
+Kernel backend selection lives here too: the ``kernels/*/ops.py``
 dispatchers call :func:`kernel_backend` once per process (lazily, on the
 first kernel call — never at import) and get one of
 ``"pallas-tpu"`` (compiled Pallas on a real TPU), ``"pallas-interpret"``
@@ -39,7 +33,6 @@ from __future__ import annotations
 
 import contextlib
 import functools
-import inspect
 import os
 from typing import Any, Callable
 
@@ -52,25 +45,14 @@ __all__ = [
     "tree_flatten_with_path", "default_backend", "on_tpu",
     "kernel_backend", "pallas_interpret_default", "import_pallas_kernel",
     "kernel_backend_for", "version_summary", "KERNEL_BACKENDS",
+    "COMPILE_CACHE_DIR", "enable_compilation_cache",
 ]
 
 JAX_VERSION: tuple[int, ...] = tuple(
     int(p) for p in jax.__version__.split(".")[:3] if p.isdigit())
 
-
-# ---------------------------------------------------------------------------
-# tree utilities (jax.tree.* is the modern spelling; jax.tree_util the stable
-# fallback — jax.tree_map/jax.tree_leaves TOP-LEVEL aliases were removed, so
-# nothing here goes through them)
-# ---------------------------------------------------------------------------
-
-_tree_ns = getattr(jax, "tree", None)
-
-tree_map: Callable = (_tree_ns.map if _tree_ns is not None
-                      and hasattr(_tree_ns, "map") else jax.tree_util.tree_map)
-tree_leaves: Callable = (_tree_ns.leaves if _tree_ns is not None
-                         and hasattr(_tree_ns, "leaves")
-                         else jax.tree_util.tree_leaves)
+tree_map: Callable = jax.tree.map
+tree_leaves: Callable = jax.tree.leaves
 tree_flatten: Callable = jax.tree_util.tree_flatten
 tree_unflatten: Callable = jax.tree_util.tree_unflatten
 tree_structure: Callable = jax.tree_util.tree_structure
@@ -79,88 +61,39 @@ tree_flatten_with_path: Callable = jax.tree_util.tree_flatten_with_path
 
 
 # ---------------------------------------------------------------------------
-# mesh construction
+# meshes
 # ---------------------------------------------------------------------------
 
-#: ``jax.sharding.AxisType`` where it exists, else None (pre-explicit-sharding
-#: JAX: every mesh axis behaves as Auto and there is nothing to spell).
-AxisType = getattr(jax.sharding, "AxisType", None)
-
-_make_mesh_native = getattr(jax, "make_mesh", None)
-_MAKE_MESH_PARAMS: frozenset[str] = (
-    frozenset(inspect.signature(_make_mesh_native).parameters)
-    if _make_mesh_native is not None else frozenset())
+AxisType = jax.sharding.AxisType
 
 
 def make_mesh(axis_shapes: tuple[int, ...], axis_names: tuple[str, ...], *,
               axis_types: Any = "auto", devices=None) -> jax.sharding.Mesh:
-    """Portable ``jax.make_mesh``.
-
-    ``axis_types="auto"`` requests all-Auto axes on JAX versions that have
-    explicit axis types and silently omits them where the concept (and the
-    kwarg) does not exist. Pass an explicit tuple of ``compat.AxisType``
-    members to request something else (ignored on old JAX).
-    """
-    if _make_mesh_native is not None:
-        kwargs: dict[str, Any] = {}
-        if devices is not None:
-            kwargs["devices"] = devices
-        if AxisType is not None and "axis_types" in _MAKE_MESH_PARAMS:
-            types = ((AxisType.Auto,) * len(axis_names)
-                     if axis_types == "auto" else axis_types)
-            if types is not None:
-                kwargs["axis_types"] = types
-        return _make_mesh_native(axis_shapes, axis_names, **kwargs)
-    # pre-0.4.35: assemble the device grid by hand
-    from jax.experimental import mesh_utils
-    devs = mesh_utils.create_device_mesh(axis_shapes, devices=devices)
-    return jax.sharding.Mesh(devs, axis_names)
+    """``jax.make_mesh`` with all-Auto axes by default (jax 0.9.0 defaults
+    to Explicit). Pass a tuple of ``AxisType`` members for anything else."""
+    types = ((AxisType.Auto,) * len(axis_names) if axis_types == "auto"
+             else axis_types)
+    return jax.make_mesh(axis_shapes, axis_names, axis_types=types,
+                         devices=devices)
 
 
-# ---------------------------------------------------------------------------
-# default-mesh installation (set_mesh / use_mesh)
-# ---------------------------------------------------------------------------
-
-_set_mesh_native = (getattr(jax.sharding, "set_mesh", None)
-                    or getattr(jax, "set_mesh", None))
-_use_mesh_native = getattr(jax.sharding, "use_mesh", None)
-
-# Emulation state: on JAX without set_mesh, "the process default mesh" is the
-# innermost entered mesh context; we keep exactly one entered here.
-_emulated_env = contextlib.ExitStack()
+# The process default installed by set_mesh: the mesh, and the handle whose
+# __exit__ restores what was installed before it.
 _current_mesh: jax.sharding.Mesh | None = None
+_installed: Any = None
 
 
 def set_mesh(mesh: jax.sharding.Mesh | None):
-    """Install ``mesh`` as the process-wide default; returns the previous one.
-
-    On JAX with ``jax.sharding.set_mesh`` this is a passthrough. Elsewhere it
-    emulates the semantics by (re-)entering the mesh's resource-env context
-    manager for the life of the process — explicit ``NamedSharding``s keep
-    working either way, and named-axis lookups resolve against ``mesh``.
-    ``set_mesh(None)`` clears the emulated default (best-effort natively).
-
-    Caveat: the emulated default lives in jax's thread-local trace state, so
-    it is only visible to the installing thread. Threaded callers on JAX
-    without native ``set_mesh`` must call this per worker thread (or pass
-    explicit ``NamedSharding``s, which work from any thread).
-    """
-    global _current_mesh
+    """Install ``mesh`` as the default mesh (``None`` clears it); returns the
+    previously installed one. Like ``jax.sharding.set_mesh``, the default is
+    thread-local: install it from the thread that traces."""
+    global _current_mesh, _installed
     prev = _current_mesh
-    if _set_mesh_native is not None:
-        try:
-            _set_mesh_native(mesh)
-        except (TypeError, ValueError):
-            if mesh is not None:   # only clearing may be unsupported
-                raise
-            # this JAX's set_mesh cannot clear the default: the previous
-            # mesh stays installed process-wide, so keep reporting it
-            # rather than letting get_mesh() diverge from reality
-            return prev
-    else:
-        _emulated_env.close()
-        if mesh is not None:
-            _emulated_env.enter_context(mesh)
+    if _installed is not None:
+        _installed.__exit__(None, None, None)
+        _installed = None
+    if mesh is not None:
+        _installed = jax.sharding.set_mesh(mesh)
     _current_mesh = mesh
     return prev
 
@@ -172,44 +105,19 @@ def get_mesh() -> jax.sharding.Mesh | None:
 
 @contextlib.contextmanager
 def use_mesh(mesh: jax.sharding.Mesh):
-    """Scoped default mesh: native ``jax.sharding.use_mesh`` where available,
-    the classic ``with mesh:`` resource env elsewhere."""
-    cm = _use_mesh_native(mesh) if _use_mesh_native is not None else mesh
-    with cm:
+    """Scoped default mesh: inside, ``jax.sharding.get_abstract_mesh()`` is
+    ``mesh``'s, so sharding hints (``models.layers.constrain``) apply."""
+    with jax.sharding.set_mesh(mesh):
         yield mesh
-
-
-# ---------------------------------------------------------------------------
-# shard_map
-# ---------------------------------------------------------------------------
-
-_shard_map_native = getattr(jax, "shard_map", None)
-if _shard_map_native is None:
-    from jax.experimental.shard_map import shard_map as _shard_map_native
-_SHARD_MAP_PARAMS: frozenset[str] = frozenset(
-    inspect.signature(_shard_map_native).parameters)
 
 
 def shard_map(f: Callable, *, mesh, in_specs, out_specs,
               check_vma: bool | None = None, **kwargs) -> Callable:
-    """Portable ``shard_map``.
-
-    ``check_vma`` is the modern name for replication/varying-manual-axes
-    checking; it is forwarded as ``check_rep`` on JAX where shard_map still
-    lives in ``jax.experimental``. Unknown extra kwargs are forwarded only if
-    the installed signature accepts them (e.g. ``auto=...``).
-    """
-    kw: dict[str, Any] = dict(mesh=mesh, in_specs=in_specs,
-                              out_specs=out_specs)
+    """``jax.shard_map``; ``check_vma=None`` keeps JAX's default."""
     if check_vma is not None:
-        if "check_vma" in _SHARD_MAP_PARAMS:
-            kw["check_vma"] = check_vma
-        elif "check_rep" in _SHARD_MAP_PARAMS:
-            kw["check_rep"] = check_vma
-    for k, v in kwargs.items():
-        if k in _SHARD_MAP_PARAMS:
-            kw[k] = v
-    return _shard_map_native(f, **kw)
+        kwargs["check_vma"] = check_vma
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, **kwargs)
 
 
 # ---------------------------------------------------------------------------
@@ -289,12 +197,30 @@ def kernel_backend_for(kernel_module) -> str:
     return "xla" if kernel_module is None else kernel_backend()
 
 
+#: The persistent compilation cache's home when ``JAX_COMPILATION_CACHE_DIR``
+#: is unset: one fixed directory in the checkout (listed in .gitignore).
+#: The path is part of the cache key, so it never moves.
+COMPILE_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".jax_cache")
+
+
+def enable_compilation_cache() -> str:
+    """Turn on JAX's persistent compilation cache; returns its directory.
+
+    Entry points call this at start-up, never at import. Where
+    ``JAX_COMPILATION_CACHE_DIR`` is set, JAX has already read it and
+    nothing else is set here; otherwise the cache goes to
+    :data:`COMPILE_CACHE_DIR`."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR", "").strip()
+    if env:
+        return env
+    jax.config.update("jax_compilation_cache_dir", COMPILE_CACHE_DIR)
+    return COMPILE_CACHE_DIR
+
+
 def version_summary() -> dict:
     """Stamp for dry-run/sweep artifacts: what actually ran this process."""
     return {"jax": jax.__version__,
             "backend": default_backend(),
-            "kernel_backend": kernel_backend(),
-            "has_axis_type": AxisType is not None,
-            "has_native_set_mesh": _set_mesh_native is not None,
-            "shard_map_home": ("jax" if hasattr(jax, "shard_map")
-                               else "jax.experimental")}
+            "kernel_backend": kernel_backend()}

@@ -1208,10 +1208,10 @@ def compile_decode_step(cfg, *, expand_masks: bool = True,
     ``pos`` is a scalar or per-row ``[R]`` vector (the continuous-batching
     form); rows are mask-major (``expand_masks=True``: row ``r`` is mask
     ``r // b``). Raises :class:`FusedPlanUnsupported` immediately when the
-    config has no fused decode lowering; the VMEM-residency / lane-alignment
-    guards of the kernel tier fire later, from the first call (trace time) —
-    callers that want the per-op fallback must catch around that first call
-    too (``serving.server.step_fns`` does).
+    config has no fused decode lowering; the kernel tier's guards (VMEM
+    residency; the compiled tier's refusal) fire later, from the first
+    call (trace time) — callers that want the per-op fallback must catch
+    around that first call too (``serving.server.step_fns`` does).
     """
     if backend not in (None, "xla", "pallas-interpret", "pallas-tpu"):
         raise ValueError(f"unknown backend {backend!r}")

@@ -55,14 +55,14 @@ def _dense(h, w, ws, b, bp, activation):
     ``ws`` (present iff the step's weight is quantized) holds the
     per-output-channel bf16 dequant scales [1, d_out_pad]; the dequant
     happens here — in VMEM, right next to the matmul — so the int8 tensor
-    is what crossed HBM."""
+    is what crossed HBM. Biases arrive as [1, d_out_pad] rows."""
     if ws is not None:
         w = w.astype(jnp.float32) * ws.astype(jnp.float32)
     y = jnp.dot(h.astype(w.dtype), w, preferred_element_type=jnp.float32)
     if b is not None:
-        y = y + b[None, :].astype(jnp.float32)
+        y = y + b.astype(jnp.float32)
     if bp is not None:
-        y = y + bp[None, :].astype(jnp.float32)
+        y = y + bp.astype(jnp.float32)
     if activation:
         y = _spec_lib.act_fn(activation)(y)
     return y
@@ -102,21 +102,33 @@ def _split_prefix(spec):
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("spec", "block_b", "moments", "interpret"))
+                   static_argnames=("spec", "block_b", "moments", "interpret",
+                                    "vmem_limit"))
 def fused_plan_pallas(x: jax.Array, params: tuple[jax.Array, ...], *,
-                      spec: _spec_lib.FusedSpec, block_b: int = 128,
-                      moments: bool = False, interpret: bool = False):
+                      spec: _spec_lib.FusedSpec, vmem_limit: int,
+                      block_b: int = 128, moments: bool = False,
+                      interpret: bool = False):
     """x [B, d_in_pad], params padded per the ops.py contract.
 
     moments=False -> samples [n_rows, B, d_out_pad]
     moments=True  -> (mean, std) [B, groups * d_out_pad]
     B must be divisible by block_b; widths must be lane-aligned (ops pads).
+    ``vmem_limit`` is the scoped-VMEM budget handed to the compiler.
     """
+    compiler_params = pltpu.CompilerParams(vmem_limit_bytes=vmem_limit)
     b, d0 = x.shape
     if b % block_b:
         raise ValueError(f"batch {b} not divisible by block_b {block_b}")
     nb = b // block_b
     slots = _spec_lib.param_slots(spec)
+    # Biases ride as [1, d] rows and per-sample biases as [n_rows, 1, d]:
+    # Mosaic wants the last two block dims (8, 128)-divisible or equal to
+    # the array's, so one sample's row of a 2-D [n_rows, d] is not a legal
+    # block (the 'ws' scales already carry that unit axis).
+    params = tuple(
+        a.reshape(a.shape[0], 1, a.shape[1]) if slot == "bp"
+        else a.reshape(1, -1) if slot == "b" else a
+        for (_, slot), a in zip(slots, params))
     table = dict(zip(slots, params))
     n_rows, groups, n_masks = spec.n_rows, spec.groups, spec.n_masks
 
@@ -171,6 +183,7 @@ def fused_plan_pallas(x: jax.Array, params: tuple[jax.Array, ...], *,
             out_shape=jax.ShapeDtypeStruct((n_rows, b, d_last), x.dtype),
             scratch_shapes=scratch,
             interpret=interpret,
+            compiler_params=compiler_params,
         )(x, *params)
 
     # ------- moments mode: grid (B/bB,), weights resident ----------------
@@ -228,6 +241,7 @@ def fused_plan_pallas(x: jax.Array, params: tuple[jax.Array, ...], *,
                    jax.ShapeDtypeStruct((b, groups * d_last), x.dtype)),
         scratch_shapes=scratch + [pltpu.VMEM((block_b, wmax), jnp.float32)],
         interpret=interpret,
+        compiler_params=compiler_params,
     )(x, *params)
 
 

@@ -26,15 +26,21 @@ from repro.kernels.pad import pad_to as _pad_to
 # importing this module never initializes jax device state.
 _kernel = compat.import_pallas_kernel("repro.kernels.fused_plan.kernel")
 
-__all__ = ["fused_plan", "fused_vmem_bytes", "FusedPlanUnsupported",
+__all__ = ["fused_plan", "fused_vmem_bytes", "check_vmem",
+           "FusedPlanUnsupported",
            "VMEM_MOMENTS_LIMIT", "KERNEL_BACKEND",
            "fused_decode", "fused_decode_vmem_bytes",
            "check_prefill_paddable"]
 
-#: Resident-footprint cap for the moments mode (all packed weights + scratch
-#: must sit in VMEM at once — the paper's on-chip-weights regime). Plans past
-#: this fall back to the per-op executor (serving/engine handles the catch).
-VMEM_MOMENTS_LIMIT = 96 * 2 ** 20
+#: Scoped-VMEM budget of the fused kernels. It is passed to Mosaic as
+#: ``vmem_limit_bytes`` (the v5e default scoped limit is 16 MiB of the
+#: chip's 128 MiB), and a kernel whose modeled footprint exceeds it raises
+#: FusedPlanUnsupported before lowering, so the per-op fallbacks in
+#: serving/engine and serving/server catch it. The moments mode needs all
+#: packed weights + scratch resident at once (the paper's on-chip-weights
+#: regime); the v5e compiler's scoped allocation tracks
+#: :func:`fused_vmem_bytes` to within 2% (tests/test_tpu_compile.py).
+VMEM_MOMENTS_LIMIT = 100 * 2 ** 20
 
 
 def __getattr__(name: str) -> str:
@@ -63,9 +69,12 @@ def _pad_params(spec: FusedSpec, params: tuple[jax.Array, ...]
 
 
 def fused_vmem_bytes(spec: FusedSpec, block_b: int = 128,
-                     bytes_per_el: int = 4) -> int:
-    """Modeled resident VMEM footprint of the moments-mode kernel: all
-    padded weight sets + 3 scratch tiles + the batch tile and outputs."""
+                     bytes_per_el: int = 4, *, moments: bool = True) -> int:
+    """Modeled resident VMEM footprint of the fused kernel: padded weights
+    + 3 scratch tiles + the double-buffered batch tile and output tiles.
+    Moments mode holds every row's weights once (constant index maps);
+    samples mode holds one row's per-sample blocks, double-buffered
+    because their index changes along the grid."""
     def pad(d: int) -> int:
         return -(-d // 128) * 128
 
@@ -74,7 +83,7 @@ def fused_vmem_bytes(spec: FusedSpec, block_b: int = 128,
     for st in spec.steps:
         if st.kind != "dense":
             continue
-        rows = spec.n_rows if st.per_sample else 1
+        rows = (spec.n_rows if moments else 2) if st.per_sample else 1
         wb = 1 if st.w_dtype == "int8" else bytes_per_el
         w_bytes += rows * pad(st.d_in) * pad(st.d_out) * wb
         if st.w_dtype:                  # bf16 per-channel scales, lane-padded
@@ -82,12 +91,26 @@ def fused_vmem_bytes(spec: FusedSpec, block_b: int = 128,
         if st.shared_bias:
             w_bytes += pad(st.d_out) * bytes_per_el
         if st.sample_bias:
-            w_bytes += spec.n_rows * pad(st.d_out) * bytes_per_el
+            w_bytes += (spec.n_rows if moments else 2) * pad(st.d_out) \
+                * bytes_per_el
         widths.append(st.d_out)
     wmax = max(pad(d) for d in widths)
-    scratch_el = 3 * block_b * wmax + block_b * pad(widths[0])
-    out_el = 2 * block_b * spec.groups * pad(widths[-1])
-    return w_bytes + (scratch_el + out_el) * bytes_per_el
+    scratch_el = 3 * block_b * wmax
+    outs = 2 * spec.groups if moments else 1          # (mean, std) | samples
+    io_el = 2 * block_b * (pad(widths[0]) + outs * pad(widths[-1]))
+    return w_bytes + (scratch_el + io_el) * bytes_per_el
+
+
+def check_vmem(spec: FusedSpec, block_b: int, *, moments: bool) -> int:
+    """Modeled footprint of the compiled kernel, or FusedPlanUnsupported
+    when it exceeds the scoped-VMEM budget it would be compiled against."""
+    need = fused_vmem_bytes(spec, block_b, moments=moments)
+    if need > VMEM_MOMENTS_LIMIT:
+        raise FusedPlanUnsupported(
+            f"{'moments' if moments else 'samples'}-mode fused plan needs "
+            f"{need} resident bytes (> {VMEM_MOMENTS_LIMIT}); use the "
+            f"per-op executor")
+    return need
 
 
 @functools.partial(jax.jit,
@@ -108,15 +131,12 @@ def fused_plan(spec: FusedSpec, x: jax.Array, params: tuple[jax.Array, ...],
         interpret = compat.pallas_interpret_default()
     b = x.shape[0]
     block_b = min(block_b, max(8, 1 << (b - 1).bit_length()))
-    if moments and fused_vmem_bytes(spec, block_b) > VMEM_MOMENTS_LIMIT:
-        raise FusedPlanUnsupported(
-            f"moments-mode fused plan needs "
-            f"{fused_vmem_bytes(spec, block_b)} resident bytes "
-            f"(> {VMEM_MOMENTS_LIMIT}); use the per-op executor")
+    check_vmem(spec, block_b, moments=moments)
     xp = _pad_to(_pad_to(x, 1, 128), 0, block_b)
     pp = _pad_params(spec, tuple(params))
     out = _kernel.fused_plan_pallas(xp, pp, spec=spec, block_b=block_b,
-                                    moments=moments, interpret=interpret)
+                                    moments=moments, interpret=interpret,
+                                    vmem_limit=VMEM_MOMENTS_LIMIT)
     do = spec.d_out
     if not moments:
         return out[:, :b, :do]
@@ -148,10 +168,6 @@ def fused_decode_vmem_bytes(spec: FusedDecodeSpec,
     return total * bytes_per_el
 
 
-def _lane_aligned(*arrays: jax.Array) -> bool:
-    return all(a.ndim >= 2 and a.shape[-1] % 128 == 0 for a in arrays)
-
-
 def fused_decode(spec: FusedDecodeSpec, x: jax.Array,
                  params: tuple[jax.Array, ...],
                  caches: tuple[jax.Array, ...], pos: jax.Array,
@@ -164,9 +180,10 @@ def fused_decode(spec: FusedDecodeSpec, x: jax.Array,
     step, pos [R], cos/sin [R, rot/2] ->
     ``(mean_logp [b, V], rel_unc [b], k_new, v_new)``. interpret=None ->
     auto (True off-TPU). Raises :class:`FusedPlanUnsupported` when the
-    resident footprint exceeds the VMEM guard, or on a compiled-TPU tier
-    with lane-unaligned serving shapes (the interpreter tier has no
-    alignment constraint) — callers fall back to the per-op decode path.
+    resident footprint exceeds the VMEM guard, and always on the compiled
+    pallas-tpu tier: Mosaic cannot lower the kernel's per-row KV gather
+    (``ref.decode_attn_ref``; tests/test_tpu_compile.py pins the refusal),
+    so on the chip callers fall back to the per-op decode path.
     """
     if compat.kernel_backend_for(_kernel) == "xla":
         return _ref.fused_decode_ref(spec, x, params, caches, pos, cos, sin)
@@ -178,13 +195,10 @@ def fused_decode(spec: FusedDecodeSpec, x: jax.Array,
         raise FusedPlanUnsupported(
             f"fused decode step needs {need} resident bytes "
             f"(> {VMEM_MOMENTS_LIMIT}); use the per-op decode path")
-    if not interpret and not _lane_aligned(x, *caches):
-        # The compiled Mosaic tier wants 128-lane shapes; serving decode
-        # pools are validated on the interpreter tier, so a lane-unaligned
-        # pool on real TPU degrades to the per-op path instead of crashing.
+    if not interpret:
         raise FusedPlanUnsupported(
-            "fused decode kernel requires 128-lane-aligned shapes on the "
-            "compiled pallas-tpu tier; use the per-op decode path")
+            "the fused decode kernel has no Mosaic lowering (per-row KV "
+            "gather); use the per-op decode path")
     return _kernel.fused_decode_pallas(x, tuple(params), tuple(caches), pos,
                                        cos, sin, spec=spec,
                                        interpret=interpret)

@@ -42,19 +42,19 @@ def _ffn_kernel(x_ref, w1_ref, b1_ref, w2_ref, b2_ref, o_ref, h_ref):
 
     x_ref  [bB, D]   — batch tile (changes every inner step)
     w1_ref [1, D, K] — sample n's packed first-layer weights (outer-only index)
-    b1_ref [1, K]
+    b1_ref [1, 1, K]
     w2_ref [1, K, D2]
-    b2_ref [D2]
+    b2_ref [1, D2]
     o_ref  [1, bB, D2]
     h_ref  [bB, K]   — VMEM scratch: the intermediate layer cache
     """
     x = x_ref[...]
     h_ref[...] = jnp.maximum(
         jnp.dot(x, w1_ref[0], preferred_element_type=jnp.float32)
-        + b1_ref[0][None, :].astype(jnp.float32), 0.0)
+        + b1_ref[0].astype(jnp.float32), 0.0)
     y = jnp.dot(h_ref[...].astype(x.dtype), w2_ref[0],
                 preferred_element_type=jnp.float32)
-    o_ref[0] = (y + b2_ref[...][None, :].astype(jnp.float32)).astype(o_ref.dtype)
+    o_ref[0] = (y + b2_ref[...].astype(jnp.float32)).astype(o_ref.dtype)
 
 
 def _ffn_kernel_q(x_ref, w1_ref, s1_ref, b1_ref, w2_ref, s2_ref, b2_ref,
@@ -69,9 +69,9 @@ def _ffn_kernel_q(x_ref, w1_ref, s1_ref, b1_ref, w2_ref, s2_ref, b2_ref,
     h_ref[...] = jnp.maximum(
         jnp.dot(x.astype(jnp.float32), w1,
                 preferred_element_type=jnp.float32)
-        + b1_ref[0][None, :].astype(jnp.float32), 0.0)
+        + b1_ref[0].astype(jnp.float32), 0.0)
     y = jnp.dot(h_ref[...], w2, preferred_element_type=jnp.float32)
-    o_ref[0] = (y + b2_ref[...][None, :].astype(jnp.float32)).astype(o_ref.dtype)
+    o_ref[0] = (y + b2_ref[...].astype(jnp.float32)).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("block_b", "sample_major",
@@ -119,10 +119,16 @@ def masked_ffn_pallas(x: jax.Array, w1p: jax.Array, b1p: jax.Array,
     x_spec = pl.BlockSpec((block_b, d), lambda i, j, f=batch_ix: (f(i, j), 0))
     w1_spec = pl.BlockSpec((1, d, k),
                            lambda i, j, f=sample_ix: (f(i, j), 0, 0))
-    b1_spec = pl.BlockSpec((1, k), lambda i, j, f=sample_ix: (f(i, j), 0))
+    # Biases ride as [N, 1, K] / [1, D2]: Mosaic wants the last two block
+    # dims (8, 128)-divisible or equal to the array's, so a per-sample row
+    # of a 2-D [N, K] array is not a legal block.
+    b1p = b1p.reshape(n, 1, k)
+    b2 = b2.reshape(1, d2)
+    b1_spec = pl.BlockSpec((1, 1, k),
+                           lambda i, j, f=sample_ix: (f(i, j), 0, 0))
     w2_spec = pl.BlockSpec((1, k, d2),
                            lambda i, j, f=sample_ix: (f(i, j), 0, 0))
-    b2_spec = pl.BlockSpec((d2,), lambda i, j: (0,))
+    b2_spec = pl.BlockSpec((1, d2), lambda i, j: (0, 0))
     if w1s is None:
         kernel = _ffn_kernel
         in_specs = [x_spec, w1_spec, b1_spec, w2_spec, b2_spec]

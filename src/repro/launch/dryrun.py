@@ -1,13 +1,16 @@
 import os
 os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
                            + " --xla_force_host_platform_device_count=512")
+os.environ["JAX_PLATFORMS"] = "cpu"
 
 """Multi-pod dry-run: lower + compile every (arch x shape) cell on the
 production mesh and extract the roofline terms from the compiled artifact.
 
-The two lines above MUST stay the first statements in this module — jax
+The lines above MUST stay the first statements in this module — jax
 locks the device count at first init, and only the dry-run may see 512
-placeholder devices (tests/benches see 1).
+placeholder devices (tests/benches see 1). The dry-run is CPU-only: on a
+machine with a TPU it (and every launch/sweep.py child) leaves the chip
+to the one process that serves on it.
 
 Per cell this produces:
   * proof of coherence: .lower().compile() succeeds under the 16x16

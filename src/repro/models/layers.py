@@ -322,6 +322,12 @@ def attention_decode(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
     if k_scale is not None:
         k_cache = k_cache.astype(jnp.float32) * k_scale[..., None]
         v_cache = v_cache.astype(jnp.float32) * v_scale[..., None]
+    elif k_cache.dtype != q.dtype:
+        # a cache narrower than the model (bf16 KV under an f32 model) is
+        # read up to the model dtype, as the fused decode kernel does —
+        # otherwise the probabilities and the combine round to bf16
+        k_cache = k_cache.astype(q.dtype)
+        v_cache = v_cache.astype(q.dtype)
     s = _grouped_scores(q, k_cache) / math.sqrt(dh)     # [B,Hkv,G,1,Smax]
     pos = jnp.asarray(pos, jnp.int32)
     qpos = pos[:, None] if pos.ndim else pos

@@ -50,6 +50,8 @@ import dataclasses
 import itertools
 from typing import Callable
 
+import jax
+
 from repro.distributed import elastic
 from repro.distributed.straggler import StragglerMonitor
 from repro.obs import registry as obs_registry
@@ -252,10 +254,15 @@ class ServingRouter:
             if name != "pod":
                 self._chips_per_host *= int(extent)
         now = self._clock()
+        # Without a mesh, host i's params and KV pool live on device
+        # i mod device count: one chip per host where the machine has them.
+        devices = jax.devices() if mesh is None else [None]
         self.hosts = [
             _Host(index=i,
-                  server=BayesianLMServer(model, params, cfg, mesh=mesh,
-                                          clock=clock, tracer=tracer),
+                  server=BayesianLMServer(
+                      model, params, cfg, mesh=mesh,
+                      device=devices[i % len(devices)],
+                      clock=clock, tracer=tracer),
                   monitor=StragglerMonitor(
                       window=rcfg.straggler_window,
                       straggler_factor=rcfg.straggler_factor,

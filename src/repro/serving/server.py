@@ -176,7 +176,8 @@ def step_fns(model: Model, expand_masks: bool = True,
     surfaces ``FusedPlanUnsupported``; ``False`` forces the per-op
     ``transformer.decode_step`` path; ``None`` (default) tries fused and
     falls back per-op when the config has no fused lowering or the kernel
-    tier's VMEM/alignment guards fire (at first call).
+    tier's guards fire (at first call: VMEM residency, and always on the
+    compiled pallas-tpu tier, which has no lowering of the decode kernel).
 
     ``prefill_buckets`` selects the admission prefill's length-bucket set:
     ``None`` (default) resolves to the power-of-two set per ``max_seq``
@@ -306,7 +307,7 @@ def _step_fns(cfg, expand_masks: bool, fused: bool | None,
 
         def decode(params, caches, tokens, pos):
             # Fused-first with a per-POOL-SHAPE per-op fallback: the kernel
-            # tier's VMEM-residency / lane-alignment guards fire at trace
+            # tier's VMEM-residency / compiled-tier guards fire at trace
             # time, from the first call with each pool shape, and depend on
             # that shape — one oversized pool must not silently demote
             # every other server on the same config.
@@ -527,10 +528,16 @@ class BayesianLMServer:
 
     def __init__(self, model: Model, params: Params,
                  cfg: ServerConfig = ServerConfig(), *, mesh=None,
+                 device=None,
                  clock: Callable[[], float] | None = None,
                  tracer: obs_trace.Tracer | None = None) -> None:
+        """``device`` pins the pool to one device: the params and the KV
+        pool are placed there, and every jitted step follows its committed
+        operands (the multi-host router gives each host its own chip)."""
         if not model.cfg.bayesian:
             raise ValueError("BayesianLMServer requires mask_samples > 0")
+        if device is not None:
+            params = jax.device_put(params, device)
         # The jit-cached step closures are process-global, so the default
         # tracer is the process TRACER; cfg.trace=True switches it on.
         self._tracer = obs_trace.TRACER if tracer is None else tracer
@@ -557,6 +564,8 @@ class BayesianLMServer:
                               donate_argnums=_donate_argnums(0))
         self._caches = transformer.init_cache(mcfg, self.schedule.rows,
                                               cfg.max_seq)
+        if device is not None:
+            self._caches = jax.device_put(self._caches, device)
         self._slots: list[int | None] = [None] * cfg.max_slots
         self._queue: list[tuple[int, int, int]] = []   # (prio, seq, req_id)
         self._seq = itertools.count()

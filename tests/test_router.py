@@ -447,3 +447,60 @@ def test_server_req_id_pinning_and_cancel(small):
     with pytest.raises(ValueError, match="nothing left to run"):
         srv.submit_scan(plan, x, chunk=16,
                         resume_results=[object(), object()])
+
+
+# ---------------------------------------------------------------------------
+# one device per host
+# ---------------------------------------------------------------------------
+
+_PLACEMENT_SNIPPET = """
+import jax, numpy as np
+from repro.configs import registry
+from repro.models import build_model
+from repro.obs.trace import ManualClock
+from repro.serving import (BayesianLMServer, RouterConfig, ServerConfig,
+                           ServingRouter)
+devs = jax.devices()
+assert len(devs) == 4, devs
+cfg = registry.smoke_config("qwen2-1.5b", n_layers=2)
+model = build_model(cfg)
+params = model.init(jax.random.PRNGKey(0))
+scfg = ServerConfig(max_slots=2, max_prompt_len=8, max_new_tokens=3)
+clock = ManualClock()
+router = ServingRouter(model, params, scfg, RouterConfig(n_hosts=4),
+                       clock=clock)
+for i, hs in enumerate(router.hosts):
+    for tree in (hs.server.params, hs.server._caches):
+        placed = {d for leaf in jax.tree.leaves(tree) for d in leaf.devices()}
+        assert placed == {devs[i]}, (i, placed)
+prompts = np.asarray(jax.random.randint(jax.random.PRNGKey(1), (4, 6), 0,
+                                        cfg.vocab_size))
+rids = [router.submit(p) for p in prompts]
+router.run(max_steps=200, tick=lambda: clock.advance(1.0))
+assert sorted(router.result(r).home for r in rids) == [0, 1, 2, 3]
+single = BayesianLMServer(model, params, scfg, device=devs[0])
+sids = [single.submit(p) for p in prompts]
+single.run()
+for r, s in zip(rids, sids):
+    assert router.result(r).generated == single.result(s).generated
+    np.testing.assert_allclose(router.result(r).uncertainty,
+                               single.result(s).uncertainty, atol=1e-6)
+print("PLACEMENT_OK")
+"""
+
+
+def test_router_hosts_on_distinct_devices():
+    """On a 4-device host, router host i keeps its params and KV pool on
+    device i and answers like a single-host server on device 0."""
+    import os
+    import subprocess
+    import sys
+    env = dict(os.environ)
+    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+    env["JAX_PLATFORMS"] = "cpu"
+    src = os.path.join(os.path.dirname(__file__), "..", "src")
+    env["PYTHONPATH"] = src + os.pathsep + env.get("PYTHONPATH", "")
+    out = subprocess.run([sys.executable, "-c", _PLACEMENT_SNIPPET], env=env,
+                         capture_output=True, text=True, timeout=600)
+    assert out.returncode == 0, out.stderr[-4000:]
+    assert "PLACEMENT_OK" in out.stdout
