@@ -20,6 +20,7 @@ from repro.kernels.fused_plan.ref import (FusedDecodeSpec,
                                           FusedPlanUnsupported, FusedSpec,
                                           check_prefill_paddable,
                                           param_slots)
+from repro.kernels.pad import VMEM_LIMIT
 from repro.kernels.pad import pad_to as _pad_to
 
 # None iff Pallas is absent (the xla tier); backend probing stays lazy so
@@ -32,15 +33,15 @@ __all__ = ["fused_plan", "fused_vmem_bytes", "check_vmem",
            "fused_decode", "fused_decode_vmem_bytes",
            "check_prefill_paddable"]
 
-#: Scoped-VMEM budget of the fused kernels. It is passed to Mosaic as
-#: ``vmem_limit_bytes`` (the v5e default scoped limit is 16 MiB of the
-#: chip's 128 MiB), and a kernel whose modeled footprint exceeds it raises
+#: Scoped-VMEM budget of the fused kernels (``kernels.pad.VMEM_LIMIT``). It
+#: is passed to Mosaic as ``vmem_limit_bytes``, and a kernel whose modeled
+#: footprint exceeds it raises
 #: FusedPlanUnsupported before lowering, so the per-op fallbacks in
 #: serving/engine and serving/server catch it. The moments mode needs all
 #: packed weights + scratch resident at once (the paper's on-chip-weights
 #: regime); the v5e compiler's scoped allocation tracks
 #: :func:`fused_vmem_bytes` to within 2% (tests/test_tpu_compile.py).
-VMEM_MOMENTS_LIMIT = 100 * 2 ** 20
+VMEM_MOMENTS_LIMIT = VMEM_LIMIT
 
 
 def __getattr__(name: str) -> str:

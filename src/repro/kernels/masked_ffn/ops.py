@@ -3,7 +3,8 @@
 Handles: backend select once per process on first call (Pallas-TPU →
 Pallas-interpret → pure-XLA reference, via ``repro.compat.kernel_backend``,
 lazy so importing never initializes jax devices), MXU-alignment
-padding (exact — see kernel.py docstring), and a convenience entry point
+padding (exact — see kernel.py docstring), the hidden-unit tile that keeps
+the kernel inside the scoped-VMEM budget, and a convenience entry point
 that takes unpacked weights + masks and does the offline packing
 (mask-zero skipping) itself.
 """
@@ -17,6 +18,7 @@ import numpy as np
 
 from repro import compat
 from repro.kernels.masked_ffn import ref as _ref
+from repro.kernels.pad import VMEM_LIMIT
 from repro.kernels.pad import pad_to as _pad_to
 
 # None iff Pallas is absent (the xla tier); backend probing stays lazy so
@@ -24,7 +26,7 @@ from repro.kernels.pad import pad_to as _pad_to
 _kernel = compat.import_pallas_kernel("repro.kernels.masked_ffn.kernel")
 
 __all__ = ["masked_ffn", "masked_ffn_all_samples", "on_tpu",
-           "KERNEL_BACKEND"]
+           "masked_ffn_vmem_bytes", "pick_block_k", "KERNEL_BACKEND"]
 
 
 def __getattr__(name: str) -> str:
@@ -35,6 +37,43 @@ def __getattr__(name: str) -> str:
 
 def on_tpu() -> bool:
     return compat.on_tpu()
+
+
+def masked_ffn_vmem_bytes(d: int, k: int, d2: int, block_b: int,
+                          block_k: int, *, x_bytes: int = 4,
+                          w_bytes: int = 4) -> int:
+    """Modeled scoped-VMEM footprint of one kernel launch at padded widths:
+    double-buffered x / w1 / w2 / output tiles and bias (+ int8 scale)
+    rows, the f32 accumulator and hidden scratch, the weight, x and output
+    tiles once more as f32 values (dequantized, or re-laid when K is
+    tiled), and 1 MiB of compiler slack. On a described v5e the compiler
+    never needed more than this (tests/test_tpu_compile.py compiles at the
+    chosen tile)."""
+    io = (block_b * d * x_bytes + d * block_k * w_bytes
+          + block_k * d2 * w_bytes + block_b * d2 * x_bytes
+          + (block_k + d2) * (4 + (2 if w_bytes == 1 else 0)))
+    scratch = block_b * (d2 + block_k) * 4
+    values = (d * block_k + block_k * d2 + block_b * (d + d2)) * 4
+    return 2 * io + scratch + values + 2 ** 20
+
+
+def pick_block_k(d: int, k: int, d2: int, block_b: int, *, x_bytes: int = 4,
+                 w_bytes: int = 4, limit: int = VMEM_LIMIT) -> int:
+    """Widest 128-multiple hidden tile dividing K whose modeled footprint
+    fits ``limit`` — K itself when one sample's weights fit, which keeps
+    the batch-level scheme's one weight load per sample. Raises ValueError
+    when not even a 128-unit tile fits (the widths are then too large for
+    this kernel's whole-D / whole-D2 tiles)."""
+    for bk in range(k, 0, -128):
+        if k % bk == 0 and masked_ffn_vmem_bytes(
+                d, k, d2, block_b, bk, x_bytes=x_bytes,
+                w_bytes=w_bytes) <= limit:
+            return bk
+    need = masked_ffn_vmem_bytes(d, k, d2, block_b, 128, x_bytes=x_bytes,
+                                 w_bytes=w_bytes)
+    raise ValueError(
+        f"masked_ffn: D={d}, D2={d2} need {need} bytes of scoped VMEM even "
+        f"at a 128-unit hidden tile (> {limit})")
 
 
 @functools.partial(jax.jit, static_argnames=("block_b", "sample_major",
@@ -53,7 +92,7 @@ def masked_ffn(x: jax.Array, w1p: jax.Array, b1p: jax.Array,
     happens in VMEM next to the matmul (or in the oracle on the xla tier).
     Zero-padding D/K/D2 to 128 and B to block_b is exact (relu(0)=0 and the
     padded w2p rows are zero; padded scale columns pair with zero weight
-    columns).
+    columns). The hidden units are tiled per :func:`pick_block_k`.
     interpret=None -> auto (True off-TPU).
     """
     if (w1s is None) != (w2s is None):
@@ -73,9 +112,13 @@ def masked_ffn(x: jax.Array, w1p: jax.Array, b1p: jax.Array,
     if w1s is not None:
         scales["w1s"] = _pad_to(w1s, 2, 128)
         scales["w2s"] = _pad_to(w2s, 2, 128)
+    block_k = pick_block_k(xp.shape[1], w1p_.shape[2], w2p_.shape[2],
+                           block_b, x_bytes=xp.dtype.itemsize,
+                           w_bytes=w1p_.dtype.itemsize)
     y = _kernel.masked_ffn_pallas(xp, w1p_, b1p_, w2p_, b2_, **scales,
-                                  block_b=block_b,
+                                  block_b=block_b, block_k=block_k,
                                   sample_major=sample_major,
+                                  vmem_limit=VMEM_LIMIT,
                                   interpret=interpret)
     return y[:, :b, :d2]
 

@@ -19,9 +19,10 @@ that stream:
   tests/test_serving_server.py). The decode step runs the *fused*
   single-launch executor (``core.plan.compile_decode_step`` — KV gather,
   attention over the slot pool, the Bayesian FFN and the Welford posterior
-  in ONE ``kernels/fused_plan`` launch) whenever the config has a fused
-  lowering, with the per-op ``transformer.decode_step`` path as the
-  ``FusedPlanUnsupported`` fallback;
+  in ONE ``kernels/fused_plan`` launch) on the pallas-interpret tier
+  whenever the config has a fused lowering, with the per-op
+  ``transformer.decode_step`` path as the ``FusedPlanUnsupported``
+  fallback and the only path on the xla and compiled pallas-tpu tiers;
 * **first-class uncertainty** — every decode step returns the per-request
   relative uncertainty; consecutive flagged tokens drive per-request
   escalation state, and the policy can early-terminate (``"terminate"``) or
@@ -174,10 +175,11 @@ def step_fns(model: Model, expand_masks: bool = True,
     ``engine.predict_packed(fused=)`` does: ``True`` requires the fused
     single-launch decode step (``core.plan.compile_decode_step``) and
     surfaces ``FusedPlanUnsupported``; ``False`` forces the per-op
-    ``transformer.decode_step`` path; ``None`` (default) tries fused and
-    falls back per-op when the config has no fused lowering or the kernel
-    tier's guards fire (at first call: VMEM residency, and always on the
-    compiled pallas-tpu tier, which has no lowering of the decode kernel).
+    ``transformer.decode_step`` path; ``None`` (default) tries fused on the
+    pallas-interpret tier and falls back per-op when the config has no
+    fused lowering or the kernel's VMEM-residency guard fires at first
+    call; on the xla and compiled pallas-tpu tiers (the decode kernel has
+    no Mosaic lowering) it decodes per-op from the start.
 
     ``prefill_buckets`` selects the admission prefill's length-bucket set:
     ``None`` (default) resolves to the power-of-two set per ``max_seq``
@@ -274,14 +276,16 @@ def _step_fns(cfg, expand_masks: bool, fused: bool | None,
 
     fused_step = fspec = None
     if fused is not False:
-        # On the xla kernel tier there is no launch to fuse — the "fused"
-        # executor would just be the fully unrolled reference graph (L
-        # layers × H heads in Python), which traces/compiles far slower
-        # than the per-op scanned decode for identical math. Auto-select
-        # prefers per-op there; fused=True still forces the ref form
-        # (in-process A/B and the forced-xla CI leg rely on it).
+        # Auto-select tries fused only on the pallas-interpret tier. On the
+        # xla tier there is no launch to fuse — the "fused" executor would
+        # just be the fully unrolled reference graph (L layers × H heads in
+        # Python), which traces/compiles far slower than the per-op scanned
+        # decode for identical math. On the compiled pallas-tpu tier the
+        # decode kernel has no Mosaic lowering (its per-row KV gather), so
+        # every trace would fail. fused=True still forces it (in-process
+        # A/B and the forced-xla CI leg rely on it; on the chip it raises).
         from repro.kernels.fused_plan import ops as fp_ops
-        if fused or fp_ops.KERNEL_BACKEND != "xla":
+        if fused or fp_ops.KERNEL_BACKEND == "pallas-interpret":
             try:
                 fspec = plan_lib.decode_fused_spec(
                     cfg, expand_masks=expand_masks)
@@ -531,9 +535,10 @@ class BayesianLMServer:
                  device=None,
                  clock: Callable[[], float] | None = None,
                  tracer: obs_trace.Tracer | None = None) -> None:
-        """``device`` pins the pool to one device: the params and the KV
-        pool are placed there, and every jitted step follows its committed
-        operands (the multi-host router gives each host its own chip)."""
+        """``device`` pins the pool to one device: the params, the KV pool
+        and every voxel scan's input and plan weights are placed there, and
+        every jitted step follows its committed operands (the multi-host
+        router gives each host its own chip)."""
         if not model.cfg.bayesian:
             raise ValueError("BayesianLMServer requires mask_samples > 0")
         if device is not None:
@@ -545,6 +550,7 @@ class BayesianLMServer:
             self._tracer.enable()
         self.model, self.params, self.cfg, self.mesh = model, params, cfg, \
             mesh
+        self.device = device
         self.schedule = scheduler_lib.SlotSchedule(model.cfg.mask_samples,
                                                    cfg.max_slots)
         # cfg.kv_dtype rewrites the MODEL config the steps/caches build
@@ -663,6 +669,14 @@ class BayesianLMServer:
             raise ValueError(
                 f"resume_results carries {len(resume_results)} chunks but "
                 f"the scan only has {len(bounds)}: nothing left to run")
+        if self.device is not None:
+            x, params = jax.device_put((x, plan.params), self.device)
+            plan = dataclasses.replace(plan, params=params)
+            # chunks a failed-over scan brings from another host's device
+            resume_results = [
+                r if r[0].devices() == {self.device}
+                else jax.device_put(r, self.device)
+                for r in resume_results or ()]
         runner = engine_lib.plan_chunk_runner(plan, backend=backend,
                                               fused=fused)
         rid = self._claim_id(req_id)

@@ -485,13 +485,31 @@ for r, s in zip(rids, sids):
     assert router.result(r).generated == single.result(s).generated
     np.testing.assert_allclose(router.result(r).uncertainty,
                                single.result(s).uncertainty, atol=1e-6)
+from repro.ivim import model as ivim_model
+from repro.serving import engine
+icfg = ivim_model.IvimConfig(n_masks=cfg.mask_samples, scale=2.0)
+plan = ivim_model.pack_for_serving(icfg, *ivim_model.init(
+    icfg, jax.random.PRNGKey(2)))
+x = np.random.default_rng(0).uniform(size=(48, icfg.width)).astype(np.float32)
+sids = [router.submit_scan(plan, x, chunk=16) for _ in range(4)]
+router.run(max_steps=200, tick=lambda: clock.advance(1.0))
+ref = engine.predict_packed(plan, x, chunk=16)
+assert sorted(router.result(r).home for r in sids) == [0, 1, 2, 3]
+assert router.summary().spills == 0
+for r in sids:
+    rec = router.result(r)
+    placed = {d for m, s in rec.final.chunk_results for d in m.devices()}
+    assert placed == {devs[rec.home]}, (rec.home, placed)
+    for got, want in zip(rec.scan_moments(), ref):
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 print("PLACEMENT_OK")
 """
 
 
 def test_router_hosts_on_distinct_devices():
     """On a 4-device host, router host i keeps its params and KV pool on
-    device i and answers like a single-host server on device 0."""
+    device i and answers like a single-host server on device 0; a voxel
+    scan routed to host i runs its chunks on device i."""
     import os
     import subprocess
     import sys

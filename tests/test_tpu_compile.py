@@ -12,6 +12,7 @@ process at a time may load the TPU library, and every test worker imports
 this file.
 """
 
+import dataclasses
 import functools
 
 import jax
@@ -28,6 +29,7 @@ from repro.ivim import physics
 from repro.kernels.fused_plan import kernel as fp_kernel
 from repro.kernels.fused_plan import ops as fp_ops
 from repro.kernels.masked_ffn import kernel as mffn_kernel
+from repro.kernels.masked_ffn import ops as mffn_ops
 from repro.models import build_model, transformer
 from repro.serving import server as server_lib
 
@@ -100,22 +102,45 @@ def test_fused_samples_compiles_ivim(one_chip):
     _check(_compile_fused(plan, one_chip, moments=False), kernel=True)
 
 
+def _compile_masked_ffn(n, d, k, d2, one_chip, *, int8: bool,
+                        batch: int = 4096):
+    """The per-op kernel as ops.masked_ffn would launch it on the chip:
+    the wrapper's hidden tile and VMEM budget at padded widths."""
+    wdt = jnp.int8 if int8 else jnp.float32
+    args = [_sds((batch, d), jnp.float32, one_chip),
+            _sds((n, d, k), wdt, one_chip),
+            _sds((n, k), jnp.float32, one_chip),
+            _sds((n, k, d2), wdt, one_chip),
+            _sds((d2,), jnp.float32, one_chip)]
+    if int8:
+        args += [_sds((n, 1, k), jnp.bfloat16, one_chip),
+                 _sds((n, 1, d2), jnp.bfloat16, one_chip)]
+    block_k = mffn_ops.pick_block_k(d, k, d2, 128,
+                                    w_bytes=1 if int8 else 4)
+    fn = functools.partial(mffn_kernel.masked_ffn_pallas, block_b=128,
+                           block_k=block_k, vmem_limit=mffn_ops.VMEM_LIMIT)
+    return jax.jit(fn).lower(*args).compile(), block_k
+
+
 @pytest.mark.parametrize("precision", ["fp32", "int8"])
 def test_masked_ffn_compiles(precision, one_chip):
     """The per-op kernel at the IVIM dense-protocol plan's padded shapes:
     16 kernel rows (4 sub-networks x 4 masks), 104 b-values -> 128 lanes."""
-    n, d, k, d2, b = 16, 128, 128, 128, 4096
-    wdt = jnp.int8 if precision == "int8" else jnp.float32
-    args = [_sds((b, d), jnp.float32, one_chip),
-            _sds((n, d, k), wdt, one_chip), _sds((n, k), jnp.float32,
-                                                 one_chip),
-            _sds((n, k, d2), wdt, one_chip), _sds((d2,), jnp.float32,
-                                                  one_chip)]
-    if precision == "int8":
-        args += [_sds((n, 1, k), jnp.bfloat16, one_chip),
-                 _sds((n, 1, d2), jnp.bfloat16, one_chip)]
-    fn = functools.partial(mffn_kernel.masked_ffn_pallas, block_b=128)
-    _check(jax.jit(fn).lower(*args).compile(), kernel=True)
+    compiled, block_k = _compile_masked_ffn(16, 128, 128, 128, one_chip,
+                                            int8=precision == "int8")
+    assert block_k == 128
+    _check(compiled, kernel=True)
+
+
+@pytest.mark.parametrize("precision", ["fp32", "int8"])
+def test_masked_ffn_tiles_wide_widths(precision, one_chip):
+    """Widths whose per-sample weights exceed the VMEM budget take a hidden
+    tile narrower than K, and the tiled kernel compiles within it."""
+    d, k = 4096, 16384
+    compiled, block_k = _compile_masked_ffn(4, d, k, d, one_chip,
+                                            int8=precision == "int8")
+    assert 128 <= block_k < k and k % block_k == 0
+    _check(compiled, kernel=True)
 
 
 def _mlp_plan(d, h):
@@ -128,7 +153,8 @@ def _mlp_plan(d, h):
 
 def test_vmem_guard_agrees_with_compiler(one_chip):
     """A plan the guard admits compiles at the guard's budget; one just
-    over it raises FusedPlanUnsupported, which the per-op fallbacks catch."""
+    over it raises FusedPlanUnsupported, and the per-op path the fallbacks
+    then take compiles for it."""
     under = _mlp_plan(2048, 2560)
     spec, _ = plan_lib.lower_fused(under)
     need = fp_ops.fused_vmem_bytes(spec, 128)
@@ -140,6 +166,14 @@ def test_vmem_guard_agrees_with_compiler(one_chip):
     assert fp_ops.VMEM_MOMENTS_LIMIT < need < 1.1 * fp_ops.VMEM_MOMENTS_LIMIT
     with pytest.raises(fp_ops.FusedPlanUnsupported, match="resident bytes"):
         fp_ops.check_vmem(spec, 128, moments=True)
+
+    def per_op(params, x):
+        plan = dataclasses.replace(over, params=params)
+        return plan_lib.execute(plan, x, backend="pallas-tpu")
+
+    x = _sds((4096, 2176), jnp.float32, one_chip)
+    _check(jax.jit(per_op).lower(_on(over.params, one_chip), x).compile(),
+           kernel=True)
 
 
 # ---------------------------------------------------------------------------
