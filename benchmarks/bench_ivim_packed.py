@@ -121,22 +121,7 @@ def run(n_voxels: int = 20_000, n_masks: int = 8, scale: float = 2.0,
     lat_fused = plan.modeled_latency(n_voxels, fused=True)
     lat_base = plan.modeled_latency(n_voxels, packed=False, batch_level=False)
 
-    # modeled-vs-measured cross-check: the fused launch's analytic traffic
-    # against the measured fused wall clock, split weights vs activations
-    from repro.core.scheduler import TrafficModel
-    from repro.obs import crosscheck
-    model_fidelity = crosscheck.model_fidelity(
-        measured_wall_s=t_fused, n_units=n_voxels, unit="voxel",
-        step_traffic=tm_fused, units_per_step=n_voxels,
-        stages={
-            "weights": TrafficModel(tm_fused.weight_bytes, 0,
-                                    tm_fused.flops, 0),
-            "activations": TrafficModel(0, tm_fused.act_bytes, 0,
-                                        tm_fused.weight_loads),
-        })
-
     out = {
-        "model_fidelity": model_fidelity,
         "n_voxels": n_voxels,
         "n_masks": n_masks,
         "width": cfg.width,
@@ -191,9 +176,6 @@ def run(n_voxels: int = 20_000, n_masks: int = 8, scale: float = 2.0,
               f"{lat_opt * 1e6:.1f} us ({out['modeled_v5e_speedup']:.2f}x) "
               f"-> fused {lat_fused * 1e6:.1f} us "
               f"({out['modeled_v5e_fused_speedup']:.2f}x)")
-        print(f"model fidelity: measured/modeled "
-              f"{model_fidelity['ratio_measured_to_modeled']:.1f}x per "
-              f"voxel (modeled for {model_fidelity['tpu']})")
         q = out["quantized"]
         print(f"quantized: int8 fused {q['wall_fused_int8_ms']:.2f} ms, "
               f"weight bytes {q['weight_bytes_fused_int8'] / 1e3:.1f} kB vs "
@@ -221,7 +203,6 @@ def write_bench_json(out: dict, path: pathlib.Path = BENCH_JSON) -> dict:
             "keep": out["keep"],
             "sample_axis": out["sample_axis"],
         },
-        "model_fidelity": out["model_fidelity"],
         "registry_snapshot": obs_registry.REGISTRY.snapshot(),
         "wall_ms": {
             "unpacked": out["wall_unpacked_ms"],

@@ -58,9 +58,7 @@ untraced replay, and zero added jit retraces (``retrace_total`` must not
 move). ``--trace-out`` exports that replay's event log as JSONL —
 ``benchmarks/verify_obs.py`` replays it into a per-request lifecycle state
 machine — and ``--metrics-out`` writes the Prometheus text exposition.
-The JSON artifact gains a ``model_fidelity`` block (measured wall time
-joined against ``core.plan.decode_traffic``'s modeled bytes, per-stage
-split from ``decode_stage_traffic``) and the full registry snapshot.
+The JSON artifact gains the full registry snapshot.
 
 Full (non-smoke) runs via ``benchmarks/run.py`` emit the canonical
 ``BENCH_serving.json`` perf-trajectory artifact.
@@ -277,7 +275,7 @@ def run(smoke: bool = False, quiet: bool = False, seed: int = 0,
     from repro.configs import registry
     from repro.core import plan as plan_lib
     from repro.models import build_model
-    from repro.obs import crosscheck, export as obs_export
+    from repro.obs import export as obs_export
     from repro.obs import registry as obs_registry
     from repro.obs import trace as obs_trace
 
@@ -443,16 +441,6 @@ def run(smoke: bool = False, quiet: bool = False, seed: int = 0,
     quantized["modeled_bytes_per_token_kv_bf16"] = plan_lib.decode_traffic(
         spec_kv16, rows, scfg.max_seq, 4, fused=True).total_bytes / max_slots
 
-    # modeled-vs-measured cross-check: join the fused server leg's wall
-    # time against the analytic decode traffic (per-stage split included)
-    model_fidelity = crosscheck.model_fidelity(
-        measured_wall_s=srv_wall, n_units=total_tokens, unit="token",
-        step_traffic=plan_lib.decode_traffic(spec, rows, scfg.max_seq,
-                                             fused=True),
-        units_per_step=max_slots,
-        stages=plan_lib.decode_stage_traffic(spec, rows, scfg.max_seq,
-                                             fused=True))
-
     if not quiet:
         mode = "smoke" if smoke else "full"
         print(f"[{mode}] {n_requests} requests, Poisson mean gap "
@@ -487,11 +475,6 @@ def run(smoke: bool = False, quiet: bool = False, seed: int = 0,
               f"{quantized['modeled_bytes_per_token_kv_f32']:,.0f} (f32 "
               f"cache) -> {quantized['modeled_bytes_per_token_kv_bf16']:,.0f}"
               f" (bf16 cache)")
-        print(f"model fidelity: measured/modeled "
-              f"{model_fidelity['ratio_measured_to_modeled']:.1f}x "
-              f"per {model_fidelity['unit']} "
-              f"(modeled for {model_fidelity['tpu']}; "
-              f"hbm bw fraction {model_fidelity['hbm_bw_fraction']:.2e})")
         print(summary.format())
         if mixed_res is not None:
             print(f"mixed pool: {mixed_res['n_scans']} scans "
@@ -535,7 +518,6 @@ def run(smoke: bool = False, quiet: bool = False, seed: int = 0,
         "mixed": mixed_res,
         "chaos": chaos_res,
         "quantized": quantized,
-        "model_fidelity": model_fidelity,
         "trace_records": trace_records,
         "trace_tokens_match": trace_tokens_match,
         "trace_zero_retrace": trace_zero_retrace,
@@ -587,7 +569,6 @@ def write_bench_json(out: dict, path: pathlib.Path = BENCH_JSON) -> dict:
         "fused_decode_active": out["fused_active"],
         "tokens_identical_fused_vs_per_op": out["fused_tokens_match"],
         "quantized": out["quantized"],
-        "model_fidelity": out["model_fidelity"],
         "trace": {
             "records": out["trace_records"],
             "tokens_bitwise_identical_vs_untraced":

@@ -12,12 +12,13 @@ promises (ci.sh runs this as the obs smoke leg):
 * span ``begin``/``end`` records nest strictly (the tracer is
   single-threaded context managers — an ``end`` must close the innermost
   open span);
-* request lifecycles are consistent: ``enqueue`` -> ``admit`` ->
-  (``token``|``chunk``|``escalate``)* -> (``preempt`` -> ``admit`` ...)* ->
-  ``finish`` — no token before admission, nothing after finish, and every
-  enqueued request finishes;
+* request lifecycles are consistent: ``enqueue`` -> ``serving.admit``
+  -> (``token``|``chunk``|``escalate``)* -> (``preempt`` ->
+  ``serving.admit`` ...)* -> ``finish`` — no token before admission,
+  nothing after finish, and every enqueued request finishes;
 * router failover lifecycles are consistent: a ``retry`` event may only
-  appear inside an open ``host_death`` or ``straggler_drain`` span (work
+  appear inside an open ``router.host_death`` or
+  ``router.straggler_drain`` span (work
   is never resubmitted without a recorded cause), ``cancel`` withdraws
   only queued work, a retried request re-enters through a fresh
   ``enqueue`` (the re-admit leg of the host-death -> retry -> re-admit
@@ -91,7 +92,7 @@ def verify_trace_events(events: list[dict]) -> list[str]:
             if cur is not None and cur != "retrying":
                 err(i, f"request {rid}: duplicate enqueue (state {cur})")
             state[rid] = "queued"
-        elif name == "admit" and kind == "begin":
+        elif name == "serving.admit" and kind == "begin":
             if cur != "queued":
                 err(i, f"request {rid}: admit from state {cur}")
             state[rid] = "running"
@@ -110,11 +111,12 @@ def verify_trace_events(events: list[dict]) -> list[str]:
             state[rid] = "retrying"
         elif name == "retry":
             # failover resubmission must carry its cause: the router only
-            # emits it inside a host_death / straggler_drain span
-            if not any(n in ("host_death", "straggler_drain")
+            # emits it inside a router.host_death / router.straggler_drain
+            # span
+            if not any(n in ("router.host_death", "router.straggler_drain")
                        for n in span_names):
-                err(i, f"request {rid}: retry outside a host_death/"
-                       f"straggler_drain span (open: {span_names})")
+                err(i, f"request {rid}: retry outside a router.host_death/"
+                       f"router.straggler_drain span (open: {span_names})")
             if cur not in ("queued", "running", "retrying"):
                 err(i, f"request {rid}: retry from state {cur}")
             state[rid] = "retrying"

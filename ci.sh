@@ -83,7 +83,8 @@ PYTHONPATH=src${PYTHONPATH:+:$PYTHONPATH} python -m benchmarks.verify_obs \
 # failed to exercise a host death with retries, or if the recovered tokens
 # are not bitwise-identical to the unfaulted run; the verifier then checks
 # the faulted run's span log (host-death -> retry -> re-admit lifecycle,
-# retry events only inside host_death/straggler_drain spans).
+# retry events only inside router.host_death/router.straggler_drain
+# spans).
 REPRO_KERNEL_BACKEND=pallas-interpret \
     PYTHONPATH=src${PYTHONPATH:+:$PYTHONPATH} python -m benchmarks.bench_serving --smoke --chaos \
     --chaos-trace-out "$obs_dir/chaos.jsonl"

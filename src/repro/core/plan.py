@@ -938,8 +938,7 @@ def fused_executor(plan: PackedPlan, *, moments: bool = False,
     spec, params = lower_fused(plan)
     runner = _fused_runner(spec, backend, moments, block_b)
 
-    def apply(x: jax.Array):
-        out = runner(x, params)
+    def epilogue(out):
         if not moments:
             return _finalize(plan, out)
         mean, std = out                 # [B, G·do], group-major columns
@@ -949,6 +948,14 @@ def fused_executor(plan: PackedPlan, *, moments: bool = False,
             mean = lo + mean * (hi - lo)
             std = std * jnp.abs(hi - lo)
         return mean, std
+
+    def apply(x: jax.Array):
+        tr = obs_trace.TRACER
+        # the jitted call, with its implicit host->device copy of x
+        with tr.span("plan.dispatch"):
+            out = runner(x, params)
+        with tr.span("plan.epilogue"):
+            return epilogue(out)
 
     return apply
 
@@ -1352,9 +1359,7 @@ def decode_stage_traffic(spec: fused_ref.FusedDecodeSpec, rows: int,
     step kind (``norm``/``attn``/``ffn``/``dense`` — attn includes its
     KV-cache bytes) plus an ``interstage`` entry holding the inter-launch
     activation traffic and the launch count. Sums field-for-field to
-    :func:`decode_traffic` (asserted in tests/test_obs.py) — the
-    ``model_fidelity`` breakdown ``obs.crosscheck`` stamps into
-    BENCH_serving.json.
+    :func:`decode_traffic` (asserted in tests/test_obs.py).
 
     Pricing is per tensor family: weights at ``bytes_per_el``, KV-cache k/v
     rows at the spec's ``kv_dtype`` width (int8 adds its per-position f32

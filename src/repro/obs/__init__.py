@@ -1,35 +1,24 @@
-"""Observability: span tracing, telemetry registry, exposition, and the
-modeled-vs-measured perf cross-check.
+"""Observability: span tracing, telemetry registry, exposition.
 
 Three parts (ROADMAP: the telemetry layer every serving follow-on reports
 through):
 
-* ``obs.trace``    — nested spans + point events into a bounded ring,
-  JSONL export; the process :data:`~repro.obs.trace.TRACER` is disabled by
-  default and switched on by ``ServerConfig(trace=True)``.
+* ``obs.trace``    — the one span API: nested spans + point events into a
+  bounded ring (JSONL export), each span also a ``jax.profiler`` annotation
+  when profiling; the process :data:`~repro.obs.trace.TRACER` has both
+  sinks off by default, ``ServerConfig(trace=True)`` switches the ring on
+  and ``REPRO_PROFILE=1`` both.
 * ``obs.registry`` — named counters/gauges/histograms (+ the opaque-key
   ``KeyedCounter`` backing ``core.plan.fused_trace_counts``) on the process
-  :data:`~repro.obs.registry.REGISTRY`; ``obs.export`` renders it as
-  Prometheus text and parses it back.
-* ``obs.crosscheck`` — joins measured wall time against the analytic
-  traffic models into the ``model_fidelity`` block of ``BENCH_*.json``
-  (import it explicitly: it reaches into ``repro.core``, which imports
-  back into this package). ``obs.profile`` adds guarded ``jax.profiler``
-  annotations.
+  :data:`~repro.obs.registry.REGISTRY`.
+* ``obs.export`` renders the registry as Prometheus text and parses it
+  back.
 
 Import-order contract: ``repro.core.plan`` (pulled in by
 ``repro.core.__init__``) imports ``obs.registry``/``obs.trace`` at module
-import time, so this package's eager imports must stay stdlib-only —
-``crosscheck`` is exposed lazily for that reason.
+import time, so this package's imports must stay stdlib-only.
 """
 
-from repro.obs import export, profile, registry, trace  # noqa: F401
+from repro.obs import export, registry, trace  # noqa: F401
 
-__all__ = ["export", "profile", "registry", "trace", "crosscheck"]
-
-
-def __getattr__(name):
-    if name == "crosscheck":
-        import importlib
-        return importlib.import_module("repro.obs.crosscheck")
-    raise AttributeError(f"module {__name__!r} has no attribute {name!r}")
+__all__ = ["export", "registry", "trace"]

@@ -1,40 +1,57 @@
-"""Lightweight nested span tracing with a bounded ring buffer.
+"""The program's one span API: nested spans and point events into a bounded
+ring, and the same spans on the JAX profiler's clock.
 
-A :class:`Tracer` records point events (``event("token", req_id=3, ...)``)
-and nested spans (``with tracer.span("admit", req_id=3): ...``) into a
-bounded in-process ``deque`` — one dict append per record, no I/O on the
-hot path — and exports the whole ring as JSONL (``export_jsonl``). Span
-begin/end records carry a span id and the enclosing span's id, so offline
-tooling (``benchmarks/verify_obs.py``) can rebuild the nesting and each
-request's full lifecycle from the log alone.
+``with TRACER.span("engine.chunk", valid=4096): ...`` is the only way the
+program opens a span. It has two sinks, each switched on its own:
 
-The module-level :data:`TRACER` is the process tracer, **disabled by
-default**: the jit-cached executors in ``core.plan`` and the lru-cached
-step closures in ``serving.server`` are process-global and cannot hold a
-per-server tracer, so they emit here and ``ServerConfig(trace=True)``
-turns it on. When disabled, ``event()`` returns after one attribute check
-and ``span()`` yields immediately — and tracing never touches traced jax
-values, so tokens/moments are bitwise-identical with tracing on or off
-(asserted in tests/test_obs.py and gated in benchmarks/bench_serving.py).
+* the **ring** -- a bounded in-process ``deque`` of begin/end records (span
+  id, enclosing span id, attrs) and point events (``event("token", ...)``):
+  one dict append per record, no I/O on the hot path, exported whole as
+  JSONL (``export_jsonl``). Offline tooling (``benchmarks/verify_obs.py``)
+  rebuilds the nesting and each request's lifecycle from it, and the
+  benchmark's span readers (``bench/spans.py``) read it after a run.
+  ``ServerConfig(trace=True)`` or :meth:`Tracer.enable` switch it on.
+* the **profiler** -- each span also enters a
+  ``jax.profiler.TraceAnnotation`` of the same name, so it lands in the
+  ``.xplane.pb`` of a profiling session on the device trace's clock.
+  :meth:`Tracer.enable_profiling` switches it on.
 
-The clock is injectable and monotonic. :data:`default_clock` is the ONE
-sanctioned wall-clock source for the serving path — serving modules take
-it as their injectable default instead of calling ``time.monotonic``
-directly (ci.sh greps for violations).
+``REPRO_PROFILE=1`` in the environment at import switches on both sinks of
+the process :data:`TRACER`. With both off, ``span()`` returns one shared
+no-op context after a single attribute check: no generator, no record, no
+per-call object. Span names are dotted by layer (``engine.*``, ``plan.*``,
+``serving.*``, ``router.*``) and are the same in both sinks. Spans wrap
+host-side dispatch only and never touch traced jax values, so outputs are
+bitwise identical and jit caches unchanged with either sink on or off
+(tests/test_obs.py).
 
-Stdlib-only by design (same import-order constraint as obs.registry).
+The module-level :data:`TRACER` is the process tracer: the jit-cached
+executors in ``core.plan`` and the lru-cached step closures in
+``serving.server`` are process-global and cannot hold a per-server tracer,
+so they emit here.
+
+The ring's clock is injectable and monotonic. :data:`default_clock` is the
+ONE sanctioned wall-clock source for the serving path -- serving modules
+take it as their injectable default instead of calling ``time.monotonic``
+directly (ci.sh's static checker rejects violations). On Linux it is the
+same ``CLOCK_MONOTONIC`` as ``time.perf_counter``, so ring times compare
+with a driver's ``perf_counter`` stamps directly. The profiler's copy of a
+span is timed by the profiler, relative to its session.
+
+Stdlib-only at import (same import-order constraint as obs.registry):
+``jax.profiler`` is imported at the first profiled span.
 """
 
 from __future__ import annotations
 
 import collections
-import contextlib
 import json
+import os
 import time
 from typing import Callable
 
 __all__ = ["Tracer", "TRACER", "get_tracer", "span", "event",
-           "default_clock", "ManualClock"]
+           "default_clock", "ManualClock", "NO_SPAN"]
 
 #: The sanctioned serving clock (monotonic; immune to wall-clock steps).
 default_clock: Callable[[], float] = time.monotonic
@@ -68,8 +85,76 @@ def _json_default(o):
     return str(o)
 
 
+class _NoSpan:
+    """The shared context ``span()`` returns with both sinks off."""
+
+    __slots__ = ()
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        return False
+
+    def set(self, **attrs) -> None:
+        pass
+
+
+#: The one no-op span (``span()`` with both sinks off).
+NO_SPAN = _NoSpan()
+
+
+def _trace_annotation(name: str):
+    from jax.profiler import TraceAnnotation
+    return TraceAnnotation(name)
+
+
+class _Span:
+    """One open span: a begin/end record pair in the ring and/or a profiler
+    annotation, as the tracer's sinks stood when the span was made."""
+
+    __slots__ = ("_tracer", "_name", "_attrs", "_to_ring", "_sid", "_ann")
+
+    def __init__(self, tracer: "Tracer", name: str, attrs: dict) -> None:
+        self._tracer, self._name, self._attrs = tracer, name, attrs
+        self._to_ring = tracer._enabled
+        self._sid = None
+        self._ann = _trace_annotation(name) if tracer._profiling else None
+
+    def __enter__(self):
+        if self._ann is not None:
+            self._ann.__enter__()
+        if self._to_ring:
+            tr = self._tracer
+            tr._next_id += 1
+            self._sid = tr._next_id
+            stack = tr._stack
+            tr._ring.append({
+                "t": tr._clock(), "name": self._name, "kind": "begin",
+                "span": self._sid, "parent": stack[-1] if stack else None,
+                "attrs": self._attrs})
+            stack.append(self._sid)
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        if self._to_ring:
+            tr = self._tracer
+            tr._stack.pop()
+            tr._ring.append({"t": tr._clock(), "name": self._name,
+                             "kind": "end", "span": self._sid, "attrs": {}})
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+        return False
+
+    def set(self, **attrs) -> None:
+        """Add attrs known only inside the span (they land on its begin
+        record)."""
+        self._attrs.update(attrs)
+
+
 class Tracer:
-    """Bounded ring of trace records. Records are plain dicts:
+    """Bounded ring of trace records, plus the profiler sink. Records are
+    plain dicts:
 
     ``{"t": float, "name": str, "kind": "event"|"begin"|"end",
        "span": id-or-None, ["parent": id-or-None,] "attrs": {...}}``
@@ -80,24 +165,46 @@ class Tracer:
 
     def __init__(self, capacity: int = 65536,
                  clock: Callable[[], float] = default_clock,
-                 enabled: bool = False) -> None:
+                 enabled: bool = False, profiling: bool = False) -> None:
         self._ring: collections.deque = collections.deque(
             maxlen=int(capacity))
         self._clock = clock
         self._enabled = bool(enabled)
+        self._profiling = bool(profiling)
+        self._on = self._enabled or self._profiling
         self._next_id = 0
         self._stack: list[int] = []
 
     # -- switches ------------------------------------------------------------
     @property
     def enabled(self) -> bool:
+        """Whether the ring records."""
         return self._enabled
 
+    @property
+    def profiling(self) -> bool:
+        """Whether spans also go to the JAX profiler."""
+        return self._profiling
+
+    def _switch(self, *, ring: bool | None = None,
+                profiling: bool | None = None) -> None:
+        if ring is not None:
+            self._enabled = ring
+        if profiling is not None:
+            self._profiling = profiling
+        self._on = self._enabled or self._profiling
+
     def enable(self) -> None:
-        self._enabled = True
+        self._switch(ring=True)
 
     def disable(self) -> None:
-        self._enabled = False
+        self._switch(ring=False)
+
+    def enable_profiling(self) -> None:
+        self._switch(profiling=True)
+
+    def disable_profiling(self) -> None:
+        self._switch(profiling=False)
 
     def configure(self, *, capacity: int | None = None,
                   clock: Callable[[], float] | None = None) -> None:
@@ -116,7 +223,8 @@ class Tracer:
 
     # -- recording -----------------------------------------------------------
     def event(self, name: str, **attrs) -> None:
-        """One point event (one append; no-op when disabled)."""
+        """One point event in the ring (one append; no-op when the ring is
+        off)."""
         if not self._enabled:
             return
         self._ring.append({
@@ -124,26 +232,14 @@ class Tracer:
             "span": self._stack[-1] if self._stack else None,
             "attrs": attrs})
 
-    @contextlib.contextmanager
     def span(self, name: str, **attrs):
-        """Nested span context: a ``begin`` record on entry, ``end`` on
-        exit; point events inside carry this span's id."""
-        if not self._enabled:
-            yield
-            return
-        self._next_id += 1
-        sid = self._next_id
-        self._ring.append({
-            "t": self._clock(), "name": name, "kind": "begin", "span": sid,
-            "parent": self._stack[-1] if self._stack else None,
-            "attrs": attrs})
-        self._stack.append(sid)
-        try:
-            yield
-        finally:
-            self._stack.pop()
-            self._ring.append({"t": self._clock(), "name": name,
-                               "kind": "end", "span": sid, "attrs": {}})
+        """Nested span context: a ``begin`` record on entry and an ``end``
+        on exit in the ring, and a profiler annotation around it; point
+        events inside carry this span's id. ``as s`` gives a handle whose
+        ``s.set(**attrs)`` adds attrs known only later."""
+        if not self._on:
+            return NO_SPAN
+        return _Span(self, name, attrs)
 
     # -- export --------------------------------------------------------------
     def events(self) -> list[dict]:
@@ -163,9 +259,12 @@ class Tracer:
         return len(self._ring)
 
 
-#: Process tracer (disabled by default — ``ServerConfig(trace=True)``
-#: enables it; benches size it via ``configure(capacity=...)``).
-TRACER = Tracer()
+_PROFILE = os.environ.get("REPRO_PROFILE", "") not in ("", "0")
+
+#: Process tracer: both sinks off by default, both on under
+#: ``REPRO_PROFILE=1``; ``ServerConfig(trace=True)`` switches the ring on,
+#: and benches size it via ``configure(capacity=...)``.
+TRACER = Tracer(enabled=_PROFILE, profiling=_PROFILE)
 
 
 def get_tracer() -> Tracer:

@@ -178,15 +178,20 @@ def predict_packed(plan: plan_lib.PackedPlan, x: jax.Array, *,
     # VoxelScanRequest path runs (server._advance_scan) — chunk for chunk,
     # so the two paths agree bitwise.
     runner = plan_chunk_runner(plan, backend=backend, fused=fused)
+    tr = obs_trace.TRACER
+    host_input = not isinstance(x, jax.Array)
     moments = []
     for lo, hi in scheduler_lib.chunk_bounds(b, chunk):
-        xc = x[lo:hi]
-        if hi - lo < chunk:
-            pad = jnp.zeros((chunk - (hi - lo),) + x.shape[1:], x.dtype)
-            xc = jnp.concatenate([xc, pad])
-        moments.append(runner(xc))
-    mean = jnp.concatenate([m for m, _ in moments])[:b]
-    std = jnp.concatenate([s for _, s in moments])[:b]
+        with tr.span("engine.chunk", valid=hi - lo, padded=chunk - (hi - lo),
+                     host_input=host_input):
+            xc = x[lo:hi]
+            if hi - lo < chunk:
+                pad = jnp.zeros((chunk - (hi - lo),) + x.shape[1:], x.dtype)
+                xc = jnp.concatenate([xc, pad])
+            moments.append(runner(xc))
+    with tr.span("engine.assemble", chunks=len(moments)):
+        mean = jnp.concatenate([m for m, _ in moments])[:b]
+        std = jnp.concatenate([s for _, s in moments])[:b]
     return mean, std
 
 
@@ -215,8 +220,9 @@ def predict_volume(plan: plan_lib.PackedPlan, volume: jax.Array, *,
         raise ValueError(f"volume must be [..., D], got {volume.shape}")
     lead = volume.shape[:-1]
     x = volume.reshape(-1, volume.shape[-1])
-    with obs_trace.TRACER.span("predict_volume", n_voxels=int(x.shape[0]),
-                               chunk=chunk, pooled=server is not None):
+    with obs_trace.TRACER.span("engine.predict_volume",
+                               n_voxels=int(x.shape[0]), chunk=chunk,
+                               pooled=server is not None):
         if server is not None:
             rid = server.submit_scan(plan, x, chunk=chunk,
                                      priority=priority, backend=backend,

@@ -449,7 +449,7 @@ class ServingRouter:
                     tr.event("fault_kill", host=hs.index, step=i)
                 continue           # silent: no step, no heartbeat
             t0 = self._clock()
-            with tr.span("host_step", host=hs.index, step=i):
+            with tr.span("router.host_step", host=hs.index, step=i):
                 hs.server.step()
             dt = (self._clock() - t0) + self.faults.delay(hs.index, i)
             hs.steps += 1
@@ -526,8 +526,8 @@ class ServingRouter:
     def _handle_death(self, hs: _Host, reason: str) -> None:
         """A host missed its heartbeat window: declare it dead, resubmit
         every resident work item, and remesh the surviving pool."""
-        with self._tracer.span("host_death", host=hs.index, reason=reason,
-                               step=self.step_i):
+        with self._tracer.span("router.host_death", host=hs.index,
+                               reason=reason, step=self.step_i):
             hs.alive = False
             hs.draining = True
             self.n_deaths += 1
@@ -572,7 +572,7 @@ class ServingRouter:
         """Persistent straggler: stop placing new work on the host,
         re-route its queued items (resident decode state is host-local and
         finishes in place), and remesh around it. Once empty it retires."""
-        with self._tracer.span("straggler_drain", host=hs.index,
+        with self._tracer.span("router.straggler_drain", host=hs.index,
                                step=self.step_i):
             hs.draining = True
             self._reassign_queued(hs, reason="straggler_drain")

@@ -61,7 +61,6 @@ from repro.core import plan as plan_lib
 from repro.core import scheduler as scheduler_lib, uncertainty as unc_lib
 from repro.models import transformer
 from repro.models.model import Model
-from repro.obs import profile as obs_profile
 from repro.obs import registry as obs_registry
 from repro.obs import trace as obs_trace
 from repro.serving.metrics import MetricsCollector, ServingSummary
@@ -151,6 +150,14 @@ class StepFns:
     fused_spec: object | None = None
     fused_state: dict | None = None
     prefill_spec: object | None = None
+    prefill_buckets: tuple[int, ...] | None = None
+
+    def prefill_bucket(self, length: int, max_seq: int) -> int | None:
+        """The bucket ``prefill`` pads a ``length``-token prompt to; None
+        where it takes the exact per-length path."""
+        if self.prefill_spec is None:
+            return None
+        return plan_lib.prefill_bucket(length, max_seq, self.prefill_buckets)
 
     def fused_live(self) -> bool:
         """True iff the decode hot loop is running the fused executor and
@@ -239,21 +246,12 @@ def _step_fns(cfg, expand_masks: bool, fused: bool | None,
 
     if prefill_spec is None:
         def prefill(params, tokens, max_seq):
-            tr = obs_trace.TRACER
-            if tr.enabled:
-                tr.event("prefill", path="exact", bucket=None,
-                         length=int(np.shape(tokens)[1]))
             return exact_prefill(params, tokens, max_seq=max_seq)
     else:
         def prefill(params, tokens, max_seq):
             toks = jnp.asarray(tokens)
             length = toks.shape[1]
             bucket = plan_lib.prefill_bucket(length, max_seq, buckets)
-            tr = obs_trace.TRACER
-            if tr.enabled:
-                tr.event("prefill",
-                         path="exact" if bucket is None else "bucketed",
-                         bucket=bucket, length=int(length))
             if bucket is None:                 # custom set doesn't cover it
                 return exact_prefill(params, toks, max_seq=max_seq)
             if bucket > length:
@@ -333,7 +331,8 @@ def _step_fns(cfg, expand_masks: bool, fused: bool | None,
         trace_counts=counts,
         fused_spec=fspec if fused_step is not None else None,
         fused_state=fused_state,
-        prefill_spec=prefill_spec)
+        prefill_spec=prefill_spec,
+        prefill_buckets=buckets)
 
 
 # ---------------------------------------------------------------------------
@@ -740,22 +739,32 @@ class BayesianLMServer:
         untouched and keep decoding. Voxel scans touch no pool cache (their
         state is the chunk cursor); the slot is pure scheduling capacity."""
         st = self.states[req_id]
-        with self._tracer.span("admit", req_id=req_id, slot=slot,
-                               kind=st.kind, resumed=st.preempts > 0):
-            if st.kind == "voxel":
+        tr = self._tracer
+        if st.kind == "voxel":
+            with tr.span("serving.admit", req_id=req_id, slot=slot,
+                         kind=st.kind, resumed=st.preempts > 0):
                 st.status, st.slot = "running", slot
                 self._slots[slot] = req_id
                 if st.preempts == 0:
                     self.metrics.on_admit(req_id)
-                return
-            ctx = list(st.request.tokens) + st.generated  # re-entry after
-            xt = jnp.tile(jnp.asarray(ctx, jnp.int32)[None],  # preempt
-                          (self.schedule.n_masks, 1))
-            with mesh_scope(self.mesh):
+            return
+        ctx = list(st.request.tokens) + st.generated  # re-entry after preempt
+        max_seq = self.cfg.max_seq
+        bucket = self.steps.prefill_bucket(len(ctx), max_seq)
+        with tr.span("serving.admit", req_id=req_id, slot=slot,
+                     kind=st.kind, resumed=st.preempts > 0,
+                     prompt_len=len(ctx), bucket=bucket), \
+                mesh_scope(self.mesh):
+            with tr.span("serving.prefill",
+                         path="exact" if bucket is None else "bucketed",
+                         bucket=bucket, length=len(ctx)):
+                xt = jnp.tile(jnp.asarray(ctx, jnp.int32)[None],
+                              (self.schedule.n_masks, 1))
                 mean, rel, fresh = self.steps.prefill(
-                    self.params, xt, max_seq=self.cfg.max_seq)
+                    self.params, xt, max_seq=max_seq)
                 self._caches = self._scatter(
                     self._caches, fresh, self.schedule.rows_for_slot(slot))
+            with tr.span("serving.sync"):
                 st.pending = int(jnp.argmax(mean[0]))
                 st.pending_unc = float(rel[0])
             st.status, st.slot = "running", slot
@@ -801,51 +810,56 @@ class BayesianLMServer:
         resident LM slot (voxel/empty slots ride along at pos -1) plus one
         fused-moments chunk launch per resident voxel scan. Returns False
         once fully idle."""
-        while self._queue and None in self._slots:
-            _, _, rid = heapq.heappop(self._queue)
-            if rid in self._cancelled:        # tombstone left by cancel()
-                self._cancelled.discard(rid)
-                continue
-            self._admit(rid, self._slots.index(None))
-        occupied = [(slot, rid) for slot, rid in enumerate(self._slots)
-                    if rid is not None]
-        if not occupied:
-            return False
-        lm = [(s, r) for s, r in occupied
-              if self.states[r].kind == "lm"]
-        voxel = [(s, r) for s, r in occupied
-                 if self.states[r].kind == "voxel"]
-        self.metrics.on_step(len(occupied), self.queue_depth,
-                             voxel_occupied=len(voxel))
-
-        with self._tracer.span("step", lm=len(lm), voxel=len(voxel),
-                               queue_depth=self.queue_depth), \
-                obs_profile.annotate("serving.step"):
+        tr = self._tracer
+        with tr.span("serving.step") as step_span:
+            while self._queue and None in self._slots:
+                _, _, rid = heapq.heappop(self._queue)
+                if rid in self._cancelled:    # tombstone left by cancel()
+                    self._cancelled.discard(rid)
+                    continue
+                self._admit(rid, self._slots.index(None))
+            occupied = [(slot, rid) for slot, rid in enumerate(self._slots)
+                        if rid is not None]
+            lm = [(s, r) for s, r in occupied
+                  if self.states[r].kind == "lm"]
+            voxel = [(s, r) for s, r in occupied
+                     if self.states[r].kind == "voxel"]
+            step_span.set(lm=len(lm), voxel=len(voxel),
+                          queue_depth=self.queue_depth)
+            if not occupied:
+                return False
+            self.metrics.on_step(len(occupied), self.queue_depth,
+                                 voxel_occupied=len(voxel))
             if lm:
                 # Inactive slots decode at pos -1: their (garbage) K/V write
                 # lands on a kpos=-1 slot, so unoccupied rows stay observably
                 # empty — voxel-occupied slots never touch the pool cache and
                 # ride along exactly like empty ones.
-                tok = np.zeros(self.cfg.max_slots, np.int32)
-                pos = np.full(self.cfg.max_slots, -1, np.int32)
-                for slot, rid in lm:
-                    st = self.states[rid]
-                    tok[slot] = st.pending
-                    pos[slot] = st.next_pos
-                rows_tok = self.schedule.row_values(jnp.asarray(tok))[:, None]
-                rows_pos = self.schedule.row_values(jnp.asarray(pos))
-                if self._tracer.enabled:
-                    self._tracer.event("decode", rows=self.schedule.rows,
-                                       slots=len(lm),
-                                       fused=self.steps.fused_live())
-                with mesh_scope(self.mesh):
-                    mean, rel, self._caches = self.steps.decode(
-                        self.params, self._caches, rows_tok, rows_pos)
-                    nxt = np.asarray(jnp.argmax(mean, -1))
-                rel = np.asarray(rel)
-                for slot, rid in lm:
-                    self._absorb(self.states[rid], int(nxt[slot]),
-                                 float(rel[slot]))
+                with tr.span("serving.decode"):
+                    tok = np.zeros(self.cfg.max_slots, np.int32)
+                    pos = np.full(self.cfg.max_slots, -1, np.int32)
+                    for slot, rid in lm:
+                        st = self.states[rid]
+                        tok[slot] = st.pending
+                        pos[slot] = st.next_pos
+                    rows_tok = self.schedule.row_values(
+                        jnp.asarray(tok))[:, None]
+                    rows_pos = self.schedule.row_values(jnp.asarray(pos))
+                    if tr.enabled:
+                        tr.event("decode", rows=self.schedule.rows,
+                                 slots=len(lm),
+                                 fused=self.steps.fused_live())
+                    with mesh_scope(self.mesh):
+                        mean, rel, self._caches = self.steps.decode(
+                            self.params, self._caches, rows_tok, rows_pos)
+                        best = jnp.argmax(mean, -1)
+                with tr.span("serving.sync"):
+                    nxt = np.asarray(best)
+                    rel = np.asarray(rel)
+                with tr.span("serving.absorb"):
+                    for slot, rid in lm:
+                        self._absorb(self.states[rid], int(nxt[slot]),
+                                     float(rel[slot]))
             for _, rid in voxel:
                 self._advance_scan(self.states[rid])
         return True
@@ -858,13 +872,16 @@ class BayesianLMServer:
         chunk_bounds``), so pooled and direct moments are bitwise equal."""
         req = st.request
         lo, hi = req.bounds[len(st.chunk_results)]
-        xc = req.x[lo:hi]
-        if hi - lo < req.chunk:
-            pad = jnp.zeros((req.chunk - (hi - lo),) + xc.shape[1:],
-                            xc.dtype)
-            xc = jnp.concatenate([xc, pad])
-        with mesh_scope(self.mesh):
-            mean, std = req.runner(xc)
+        with self._tracer.span("engine.chunk", valid=hi - lo,
+                               padded=req.chunk - (hi - lo),
+                               host_input=not isinstance(req.x, jax.Array)):
+            xc = req.x[lo:hi]
+            if hi - lo < req.chunk:
+                pad = jnp.zeros((req.chunk - (hi - lo),) + xc.shape[1:],
+                                xc.dtype)
+                xc = jnp.concatenate([xc, pad])
+            with mesh_scope(self.mesh):
+                mean, std = req.runner(xc)
         # Chunk-level uncertainty signal for the shared escalation policy:
         # the worst per-voxel relative uncertainty (max over valid voxels
         # and output columns) — "any voxel uncertain => flag the chunk".

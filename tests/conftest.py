@@ -13,8 +13,9 @@ import pytest  # noqa: E402
 def _isolate_obs_state():
     """Write-isolate the process telemetry state per test: counter values
     (obs.registry.REGISTRY — including ``core.plan.fused_trace_counts``) and
-    the process tracer's enabled flag are restored after every test, so no
-    test can leak metric mutations or a left-enabled tracer into another.
+    the process tracer's ring and profiler switches are restored after
+    every test, so no test can leak metric mutations or a left-enabled
+    tracer into another.
 
     NOTE the asymmetry this creates: counters roll back, jit/lru caches do
     NOT — a test asserting an absolute trace count ≥ 1 after an operation
@@ -25,10 +26,13 @@ def _isolate_obs_state():
 
     state = obs_registry.REGISTRY.dump_state()
     was_enabled = obs_trace.TRACER.enabled
+    was_profiling = obs_trace.TRACER.profiling
     try:
         yield
     finally:
         obs_registry.REGISTRY.restore_state(state)
+        if not was_profiling:
+            obs_trace.TRACER.disable_profiling()
         if not was_enabled:
             obs_trace.TRACER.disable()
             obs_trace.TRACER.clear()

@@ -1,10 +1,13 @@
-"""Observability subsystem: registry, tracer, exposition, cross-check, and
-the serving integration (trace-on == trace-off bitwise, verifier-clean
-lifecycle logs, summary/exposition agreement)."""
+"""Observability subsystem: registry, the one span API and its two sinks,
+exposition, and the serving integration (trace-on == trace-off bitwise,
+verifier-clean lifecycle logs, summary/exposition agreement)."""
 
 import importlib.util
 import math
+import os
 import pathlib
+import subprocess
+import sys
 
 import jax
 import numpy as np
@@ -12,13 +15,13 @@ import pytest
 
 from repro.configs import registry as cfg_registry
 from repro.core import plan as plan_lib
-from repro.core.scheduler import TrafficModel
+from repro.ivim import model as ivim_model
 from repro.models import build_model
 from repro.obs import export as export_lib
-from repro.obs import profile as profile_lib
 from repro.obs import registry as reg_lib
 from repro.obs import trace as trace_lib
 from repro.serving import BayesianLMServer, QueueFullError, ServerConfig
+from repro.serving import engine
 from repro.serving.metrics import MetricsCollector
 
 DATA = pathlib.Path(__file__).parent / "data"
@@ -249,6 +252,45 @@ def test_tracer_ring_bounded():
     assert [e["attrs"]["i"] for e in evs] == [6, 7, 8, 9]
 
 
+def test_span_with_both_sinks_off_is_one_shared_no_op():
+    """Both sinks off: every ``span()`` is the one shared no-op context (no
+    per-call object), and nothing is recorded, attrs set late included."""
+    tr = trace_lib.Tracer()
+    assert not tr.enabled and not tr.profiling
+    assert tr.span("a") is trace_lib.NO_SPAN
+    assert tr.span("b", x=1) is trace_lib.NO_SPAN
+    with tr.span("a", x=1) as s:
+        s.set(y=2)
+        tr.event("e")
+    assert tr.events() == []
+    tr.enable()
+    assert tr.span("a") is not trace_lib.NO_SPAN
+    tr.disable()
+    assert tr.span("a") is trace_lib.NO_SPAN
+
+
+def test_span_set_adds_attrs_to_its_begin_record():
+    tr = trace_lib.Tracer(clock=trace_lib.ManualClock(), enabled=True)
+    with tr.span("serving.step", a=1) as s:
+        s.set(lm=3, a=2)
+    begin, end = tr.events()
+    assert begin["attrs"] == {"a": 2, "lm": 3}
+    assert end["attrs"] == {} and end["span"] == begin["span"]
+
+
+def test_repro_profile_switches_on_both_sinks():
+    """``REPRO_PROFILE=1`` at import turns on the ring and the profiler of
+    the process tracer; unset, both are off."""
+    code = ("from repro.obs import trace; t = trace.TRACER; "
+            "print(t.enabled, t.profiling)")
+    src = str(pathlib.Path(__file__).parent.parent / "src")
+    for value, want in (("1", "True True"), ("", "False False")):
+        env = dict(os.environ, PYTHONPATH=src, REPRO_PROFILE=value)
+        out = subprocess.run([sys.executable, "-c", code], env=env,
+                             capture_output=True, text=True, check=True)
+        assert out.stdout.split() == want.split()
+
+
 # ---------------------------------------------------------------------------
 # metrics collector on the registry + injectable clock
 # ---------------------------------------------------------------------------
@@ -371,8 +413,17 @@ def test_server_trace_replays_through_verifier(small):
     events = trace_lib.TRACER.events()
     assert len(events) > 0
     names = {e["name"] for e in events}
-    assert {"enqueue", "admit", "prefill", "step", "decode", "token",
-            "finish"} <= names
+    assert {"enqueue", "serving.admit", "serving.prefill", "serving.step",
+            "serving.decode", "serving.sync", "serving.absorb", "decode",
+            "token", "finish"} <= names
+    prefills = [e for e in events
+                if e["name"] == "serving.prefill" and e["kind"] == "begin"]
+    assert prefills and all(
+        {"path", "bucket", "length"} <= set(e["attrs"]) for e in prefills)
+    admits = [e for e in events
+              if e["name"] == "serving.admit" and e["kind"] == "begin"]
+    assert all(e["attrs"]["prompt_len"] == 6 and "bucket" in e["attrs"]
+               for e in admits)
     verify_obs = _load_verify_obs()
     assert verify_obs.verify_trace_events(events) == []
     # and the exposition side of the verifier
@@ -395,8 +446,8 @@ def test_verifier_catches_violations():
     assert any("no emission before admission" in e for e in errs)
     # event after finish
     good = [ev("enqueue", 0),
-            ev("admit", 0, kind="begin", span=1, parent=None),
-            ev("admit", kind="end", span=1),
+            ev("serving.admit", 0, kind="begin", span=1, parent=None),
+            ev("serving.admit", kind="end", span=1),
             ev("token", 0), ev("finish", 0)]
     assert verify_obs.verify_trace_events(good) == []
     errs = verify_obs.verify_trace_events(good + [ev("token", 0)])
@@ -410,7 +461,7 @@ def test_verifier_catches_violations():
     assert any("backwards" in e for e in errs)
     # unbalanced spans
     errs = verify_obs.verify_trace_events(
-        [ev("step", kind="begin", span=7, parent=None)])
+        [ev("serving.step", kind="begin", span=7, parent=None)])
     assert any("never ended" in e for e in errs)
 
 
@@ -436,40 +487,66 @@ def test_queue_rejection_counted_and_traced(small):
 
 
 # ---------------------------------------------------------------------------
-# profile annotations
+# the profiler sink
 # ---------------------------------------------------------------------------
 
 
 def test_profile_annotate_guarded():
-    import contextlib
-    was = profile_lib.enabled()
-    try:
-        profile_lib.disable()
-        assert isinstance(profile_lib.annotate("x"),
-                          contextlib.nullcontext)
-        profile_lib.enable()
-        from jax.profiler import TraceAnnotation
-        assert isinstance(profile_lib.annotate("x"), TraceAnnotation)
-    finally:
-        (profile_lib.enable if was else profile_lib.disable)()
+    """The profiler sink alone: spans are real (they annotate the profiler)
+    while the ring records nothing; switched off, the no-op is back."""
+    tr = trace_lib.Tracer()
+    tr.enable_profiling()
+    assert tr.profiling and not tr.enabled
+    sp = tr.span("x", a=1)
+    assert sp is not trace_lib.NO_SPAN
+    with sp:
+        with tr.span("y"):
+            pass
+    assert tr.events() == []
+    tr.disable_profiling()
+    assert tr.span("x") is trace_lib.NO_SPAN
+
+
+def _tiny_scan():
+    cfg = ivim_model.IvimConfig(n_masks=4, scale=2.0)
+    params, state = ivim_model.init(cfg, jax.random.PRNGKey(0))
+    plan = ivim_model.pack_for_serving(cfg, params, state)
+    vol = np.asarray(jax.random.uniform(jax.random.PRNGKey(3),
+                                        (4, 3, 2, cfg.width)))
+    return plan, vol
 
 
 def test_profile_adds_no_retraces(small):
+    """With both sinks on, LM tokens and uncertainties and IVIM moments are
+    bitwise identical to the untraced run, with no added retraces."""
     cfg, model, params = small
     prompts = _prompts(cfg, 2)
-    _run_lm(model, params, prompts, trace=False)       # warm every graph
+    plan, vol = _tiny_scan()
+    off = _run_lm(model, params, prompts, trace=False)   # warm every graph
+    m_off = engine.predict_volume(plan, vol, chunk=5, backend="xla")
     rt0 = reg_lib.REGISTRY.value("retrace_total")
-    was = profile_lib.enabled()
+    tr = trace_lib.TRACER
+    tr.configure(capacity=65536)
+    tr.enable_profiling()
     try:
-        profile_lib.enable()
-        _run_lm(model, params, prompts, trace=False)
+        on = _run_lm(model, params, prompts, trace=True)
+        m_on = engine.predict_volume(plan, vol, chunk=5, backend="xla")
     finally:
-        (profile_lib.enable if was else profile_lib.disable)()
+        tr.disable_profiling()
+        tr.disable()
     assert reg_lib.REGISTRY.value("retrace_total") == rt0
+    assert off == on
+    for a, b in zip(m_off, m_on):
+        assert np.array_equal(np.asarray(a), np.asarray(b))
+    names = [e["name"] for e in tr.events() if e["kind"] == "begin"]
+    assert names.count("engine.chunk") == 5           # 24 voxels / 5
+    assert names.count("plan.dispatch") == names.count("plan.epilogue") == 5
+    assert names.count("engine.predict_volume") == 1
+    assert names.count("engine.assemble") == 1
 
 
 # ---------------------------------------------------------------------------
-# modeled-vs-measured cross-check
+# decode traffic by stage
 # ---------------------------------------------------------------------------
 
 
@@ -496,28 +573,3 @@ def test_decode_stage_traffic_sums_to_decode_traffic(small):
             assert st_f[name] == st_p[name]
     assert st_f["interstage"].act_bytes < st_p["interstage"].act_bytes
     assert st_f["interstage"].weight_loads == 1
-
-
-def test_model_fidelity_block():
-    from repro.core import latency_model
-    from repro.obs import crosscheck
-    tpu = latency_model.V5E
-    # bandwidth-bound step: 819 MB at 819 GB/s = 1 ms + 1 launch fill
-    tm = TrafficModel(weight_bytes=int(tpu.hbm_bw // 1000), act_bytes=0,
-                      flops=1, weight_loads=1)
-    modeled = 1e-3 + tpu.kernel_fill_us * 1e-6
-    assert crosscheck.roofline_seconds(tm) == pytest.approx(modeled)
-    blk = crosscheck.model_fidelity(
-        measured_wall_s=2.0, n_units=100, step_traffic=tm,
-        units_per_step=10, unit="token",
-        stages={"all": tm})
-    assert blk["unit"] == "token" and blk["tpu"] == "tpu-v5e"
-    assert blk["measured_s_per_unit"] == pytest.approx(0.02)
-    assert blk["modeled_s_per_unit"] == pytest.approx(modeled / 10)
-    assert blk["ratio_measured_to_modeled"] == pytest.approx(
-        0.02 / (modeled / 10))
-    assert blk["stages"]["all"]["byte_share"] == 1.0
-    assert blk["stages"]["all"]["modeled_s"] == pytest.approx(modeled)
-    # JSON-safe (what lands in BENCH_*.json)
-    import json
-    json.dumps(blk)

@@ -412,7 +412,7 @@ def test_traced_chaos_run_is_bitwise_and_verifier_clean(small):
     assert traced_s.host_deaths == plain_s.host_deaths
     assert verify_obs.verify_trace_events(events) == []
     names = {e["name"] for e in events}
-    assert {"host_death", "retry", "enqueue", "remesh"} <= names
+    assert {"router.host_death", "retry", "enqueue", "remesh"} <= names
 
 
 def test_server_req_id_pinning_and_cancel(small):
