@@ -95,7 +95,8 @@ def plan_chunk_runner(plan: plan_lib.PackedPlan, *,
 
     ``fused`` selects the executor exactly like ``predict_packed(fused=)``:
     ``True`` requires the whole-plan megakernel with the in-kernel moments
-    epilogue and surfaces :class:`plan_lib.FusedPlanUnsupported`; ``False``
+    epilogue — one device program per chunk, the range conversion C(.)
+    included — and surfaces :class:`plan_lib.FusedPlanUnsupported`; ``False``
     forces the per-op path (one masked-FFN launch per PackedPair, then
     ``uncertainty.predictive_moments``); ``None`` (default) tries fused and
     falls back per-op — at build when the plan has no fused lowering, or at

@@ -124,6 +124,31 @@ def test_fused_executor_cached_no_retrace():
     assert plan_lib.fused_trace_counts[(spec, "xla", True)] == 3
 
 
+@pytest.mark.parametrize("moments", [True, False])
+@pytest.mark.parametrize("family", ["ivim", "mlp"])
+def test_fused_executor_one_program_no_uploads(family, moments):
+    """The executor's whole result, C(.) and the group un-flattening
+    included, comes from one jitted program: once warm, a call on a
+    device-resident x moves nothing from host to device (the IVIM plan has
+    out_ranges and 4 groups; the MLP plan has neither)."""
+    plan, x = FAMILIES[family](4)
+    assert (plan.out_ranges is not None) == (family == "ivim")
+    assert plan.groups == (4 if family == "ivim" else 1)
+    run = plan_lib.fused_executor(plan, moments=moments, backend="xla")
+    run(x)
+    x = jax.device_put(x)
+    with jax.transfer_guard_host_to_device("disallow"):
+        got = jax.block_until_ready(run(x))
+    samples = plan_lib.execute(plan, x, backend="xla")
+    if not moments:
+        assert got.shape == samples.shape
+        _close(got, samples)
+        return
+    for g, w in zip(got, unc_lib.predictive_moments(samples)):
+        assert g.shape == w.shape
+        _close(g, w)
+
+
 # ---------------------------------------------------------------------------
 # serving engine: chunk streaming + volumes
 # ---------------------------------------------------------------------------

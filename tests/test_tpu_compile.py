@@ -102,6 +102,22 @@ def test_fused_samples_compiles_ivim(one_chip):
     _check(_compile_fused(plan, one_chip, moments=False), kernel=True)
 
 
+@pytest.mark.parametrize("moments", [True, False])
+def test_fused_runner_compiles_with_range_conversion(moments, one_chip):
+    """The engine's whole per-chunk program: the kernel with C(.) (and, for
+    samples, the group un-flattening) in one jitted ``run``, the range
+    bounds passed as device arguments."""
+    plan = _ivim_plan(physics.CLINICAL_B_VALUES)
+    spec, params = plan_lib.lower_fused(plan)
+    run = plan_lib._fused_runner(spec, "pallas-tpu", moments, 128)
+    x = _sds((4096, spec.d_in), jnp.float32, one_chip)
+    bounds = (_sds((4,), jnp.float32, one_chip),) * 2
+    compiled = run.lower(x, _on(params, one_chip), bounds).compile()
+    _check(compiled, kernel=True)
+    out = compiled.output_shardings
+    assert len(jax.tree.leaves(out)) == (2 if moments else 1)
+
+
 def _compile_masked_ffn(n, d, k, d2, one_chip, *, int8: bool,
                         batch: int = 4096):
     """The per-op kernel as ops.masked_ffn would launch it on the chip:
