@@ -73,9 +73,19 @@ class ModelConfig:
     rope_pct: float = 1.0            # stablelm-2: 0.25 partial rotary
     m_rope_sections: tuple[int, ...] = ()   # qwen2-vl M-RoPE ((16,24,24))
     local_window: int = 0            # >0: sliding-window attention size
+    # latent attention (MLA, DeepSeek-V2/V3; arXiv:2405.04434). With
+    # kv_lora_rank > 0 every attention block caches one latent of
+    # kv_lora_rank + qk_rope_dim values per position (the normed c_kv and
+    # the shared roped key part) instead of K/V heads; the queries carry
+    # qk_nope_dim + qk_rope_dim per head, the values v_head_dim.
+    kv_lora_rank: int = 0
+    qk_nope_dim: int = 0
+    qk_rope_dim: int = 0
+    v_head_dim: int = 0
 
     # ---- norms / activations / embeddings ----------------------------------
     norm: str = "rmsnorm"            # rmsnorm | layernorm
+    norm_eps: float = 1e-6
     activation: str = "silu"         # silu(SwiGLU) | gelu(GeGLU) | gelu_mlp
     tie_embeddings: bool = False
     embeds_input: bool = False       # audio/vlm prefill: frontend stub feeds
@@ -90,6 +100,18 @@ class ModelConfig:
                                      # sequence shards (no pre-MoE gather;
                                      # dispatch becomes a model-axis a2a)
     moe_dense_residual: bool = False # arctic: dense FFN in parallel with MoE
+    moe_d_ff: int = 0                # routed expert width (0 -> d_ff)
+    n_shared_experts: int = 0        # always-on experts: one FFN of
+                                     # n_shared_experts * moe_d_ff
+    first_dense_layers: int = 0      # leading dense-FFN layers
+                                     # (first_k_dense_replace)
+    router: str = "softmax"          # softmax | sigmoid_bias (DeepSeek-V3
+                                     # noaux_tc: sigmoid scores, a bias
+                                     # added for selection only)
+    norm_topk_prob: bool = False     # renormalise the chosen gate weights
+    routed_scaling: float = 1.0      # routed_scaling_factor
+    moe_dropless: bool = False       # sorted grouped dispatch (no capacity,
+                                     # no token dropped) instead of GShard
 
     # ---- hybrid (RG-LRU) ----------------------------------------------------
     lru_width: int = 0               # 0 -> d_model
@@ -146,6 +168,16 @@ class ModelConfig:
             raise ValueError(f"unknown family {self.family}")
         if self.kv_dtype not in ("", "bfloat16", "int8"):
             raise ValueError(f"unknown kv_dtype {self.kv_dtype!r}")
+        if self.router not in ("softmax", "sigmoid_bias"):
+            raise ValueError(f"unknown router {self.router!r}")
+
+    @property
+    def mla(self) -> bool:
+        return self.kv_lora_rank > 0
+
+    @property
+    def expert_d_ff(self) -> int:
+        return self.moe_d_ff or self.d_ff
 
     @property
     def resolved_head_dim(self) -> int:
@@ -175,7 +207,9 @@ class ModelConfig:
         if self.family == "audio":
             return (Segment(("attn",), L),)     # causal=False handles encoder
         if self.family == "moe":
-            return (Segment(("moe",), L),)
+            k = min(self.first_dense_layers, L)
+            return tuple(Segment(p, r) for p, r in
+                         ((("attn",), k), (("moe",), L - k)) if r)
         if self.family == "hybrid":
             # RecurrentGemma: repeating (rec, rec, attn); remainder rec-only.
             reps, rem = divmod(L, 3)
@@ -197,10 +231,22 @@ class ModelConfig:
             return tuple(segs)
         raise AssertionError(self.family)
 
+    def attn_param_count(self) -> int:
+        """Weights of one attention sub-layer (projections only)."""
+        d, h = self.d_model, self.n_heads
+        if self.mla:
+            r, rope = self.kv_lora_rank, self.qk_rope_dim
+            return (d * h * (self.qk_nope_dim + rope) + d * (r + rope) + r
+                    + r * h * (self.qk_nope_dim + self.v_head_dim)
+                    + h * self.v_head_dim * d)
+        dh = self.resolved_head_dim
+        return d * dh * (h + 2 * self.n_kv_heads) + dh * h * d
+
     def param_count(self) -> int:
-        """Approximate parameter count (for roofline MODEL_FLOPS)."""
-        d, dh = self.d_model, self.resolved_head_dim
-        qkv = d * dh * (self.n_heads + 2 * self.n_kv_heads) + dh * self.n_heads * d
+        """Parameter count, embedding and head included (for roofline
+        MODEL_FLOPS and the configurations' published sizes)."""
+        d = self.d_model
+        qkv = self.attn_param_count()
         if self.activation in ("silu", "gelu"):
             ffn = 3 * d * self.d_ff
         else:
@@ -211,8 +257,9 @@ class ModelConfig:
                 if kind in ("attn", "local_attn"):
                     per_layer += (qkv + ffn) * seg.reps
                 elif kind == "moe":
-                    expert = 3 * d * self.d_ff
+                    expert = 3 * d * self.expert_d_ff
                     layer = qkv + self.n_experts * expert + d * self.n_experts
+                    layer += self.n_shared_experts * expert
                     if self.moe_dense_residual:
                         layer += ffn
                     per_layer += layer * seg.reps
@@ -227,11 +274,11 @@ class ModelConfig:
         return per_layer + embed
 
     def active_param_count(self) -> int:
-        """Active params per token (MoE: top_k experts only)."""
+        """Active params per token (MoE: top_k routed experts only)."""
         if self.family != "moe":
             return self.param_count()
-        d = self.d_model
-        expert = 3 * d * self.d_ff
-        total = self.param_count()
-        inactive = self.n_layers * (self.n_experts - self.top_k) * expert
-        return total - inactive
+        expert = 3 * self.d_model * self.expert_d_ff
+        moe_layers = sum(seg.reps * seg.pattern.count("moe")
+                         for seg in self.segments())
+        return self.param_count() \
+            - moe_layers * (self.n_experts - self.top_k) * expert

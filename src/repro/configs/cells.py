@@ -1,7 +1,7 @@
 """Canonical (architecture x input-shape) dry-run cell enumeration.
 
-40 assigned cells total; cells that are structurally inapplicable are
-*enumerated with a skip reason* (never silently dropped):
+One cell per (arch, shape) pair, 44 in all; cells that are structurally
+inapplicable are *enumerated with a skip reason* (never silently dropped):
 
   * encoder-only archs (hubert-xlarge) have no decode step -> decode_32k and
     long_500k are skipped;

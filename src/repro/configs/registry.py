@@ -1,4 +1,4 @@
-"""Exact public configs for the 10 assigned architectures (+ reduced smoke
+"""Exact public configs for the assigned architectures (+ reduced smoke
 variants). Sources quoted per entry; fields not pinned by the assignment
 follow the cited public config, with assumptions documented inline.
 """
@@ -68,6 +68,25 @@ CONFIGS: dict[str, ModelConfig] = {
         d_ff=4864, vocab_size=32000,
         n_experts=128, top_k=2, moe_dense_residual=True,
         capacity_factor=1.25, rope_theta=10_000.0),
+
+    # [hf:moonshotai/Moonlight-16B-A3B config.json] — the DeepSeek-V3
+    # architecture (arXiv:2412.19437) at hidden 2048: MLA with no q-LoRA
+    # (latent 512 + 64 shared rope dims, 16 heads of 128 nope + 64 rope,
+    # values 128), layer 0 dense (SwiGLU 11264), then 64 routed SwiGLU
+    # experts of 1408, top-6, plus 2 shared; sigmoid router with a
+    # selection-only bias (noaux_tc, one group), renormalised and scaled
+    # by 2.446; RMSNorm eps 1e-5, RoPE theta 5e4, untied head. Serving
+    # routes dropless; attention chunks of 512 queries bound the prefill's
+    # score matrix at an 8k context.
+    "moonlight-16b-a3b": ModelConfig(
+        arch_id="moonlight-16b-a3b", family="moe",
+        source="hf:moonshotai/Moonlight-16B-A3B",
+        n_layers=27, d_model=2048, n_heads=16, n_kv_heads=16, head_dim=128,
+        d_ff=11264, vocab_size=163840, rope_theta=50_000.0, norm_eps=1e-5,
+        kv_lora_rank=512, qk_nope_dim=128, qk_rope_dim=64, v_head_dim=128,
+        n_experts=64, top_k=6, moe_d_ff=1408, n_shared_experts=2,
+        first_dense_layers=1, router="sigmoid_bias", norm_topk_prob=True,
+        routed_scaling=2.446, moe_dropless=True, attn_chunk=512),
 
     # [arXiv:2402.19427] — Griffin/RecurrentGemma: RG-LRU blocks with one
     # local-attention layer per two recurrent layers, window 2048, MQA.
@@ -147,6 +166,11 @@ def smoke_config(arch_id: str, **overrides) -> ModelConfig:
     )
     if base.m_rope_sections:
         small["m_rope_sections"] = (2, 3, 3)   # scaled to head_dim 16
+    if base.mla:
+        # every kind of layer kept: the dense layer, MLA, routed and shared
+        # experts, the sigmoid+bias router (8 experts, top-3)
+        small.update(n_layers=3, kv_lora_rank=32, qk_nope_dim=16,
+                     qk_rope_dim=8, v_head_dim=16, moe_d_ff=32, top_k=3)
     if base.family == "hybrid":
         small["n_layers"] = 4          # rec,rec,attn + rec remainder
     if base.family == "ssm":

@@ -79,7 +79,7 @@ __all__ = [
     "lower_fused", "execute_fused", "fused_executor",
     "FusedPlanUnsupported", "fused_trace_counts",
     "lower_fused_decode", "compile_decode_step", "decode_fused_spec",
-    "prefill_buckets", "prefill_bucket", "prefill_fused_spec",
+    "prefill_buckets", "prefill_bucket", "PrefillSpec", "prefill_spec",
     "compile_prefill_step",
     "decode_traffic", "decode_stage_traffic", "decode_modeled_latency",
 ]
@@ -1036,6 +1036,8 @@ def lower_fused_decode(cfg, *, expand_masks: bool = True
         raise FusedPlanUnsupported("encoder-only config has no decode step")
     if cfg.m_rope_sections:
         raise FusedPlanUnsupported("M-RoPE decode has no fused lowering")
+    if getattr(cfg, "kv_lora_rank", 0):
+        raise FusedPlanUnsupported("latent attention has no fused lowering")
     kv_dtype = getattr(cfg, "kv_dtype", "")
     if kv_dtype == "int8":
         # int8 caches carry per-position scale leaves the single-program
@@ -1268,12 +1270,13 @@ def decode_fused_spec(cfg, *, expand_masks: bool = True
 # prefill graph per bucket with the true length as a *traced* scalar: the
 # last-token logits are gathered at length-1 (causal attention makes that
 # position blind to the pad tail) and the pad tail's cache entries are
-# trimmed back to the init state — bitwise identical to an exact-length
-# prefill, with the distinct trace count bounded by the bucket set instead
-# of the prompt-length set. Support is gated through the same
-# FusedDecodeSpec lowering the fused decode step uses (lower_fused_decode +
-# kernels/fused_plan.check_prefill_paddable): configs it rejects fall back
-# to the per-length exact prefill in serving/server.step_fns.
+# trimmed back to the init state — the exact-length prefill's caches, with
+# the distinct trace count bounded by the bucket set instead of the
+# prompt-length set. Support is a property of the cache layout
+# (:func:`prefill_spec`): every cache must keep slot == position (global
+# GQA K/V, the MLA latent) and every MoE layer must route dropless, so
+# that pad tokens take no capacity from real ones. Configs it rejects fall
+# back to the per-length exact prefill in serving/server.step_fns.
 
 
 @functools.lru_cache(maxsize=None)
@@ -1316,15 +1319,49 @@ def prefill_bucket(length: int, max_seq: int,
     return None
 
 
-def prefill_fused_spec(cfg, *, expand_masks: bool = True
-                       ) -> fused_ref.FusedDecodeSpec:
-    """Static shape-key of the bucketed prefill (trace-counter key), and its
-    support gate: raises :class:`FusedPlanUnsupported` when padded-bucket
-    prefill would not be exact for ``cfg`` — no fused decode lowering
-    (MoE / recurrent / M-RoPE / non-causal), or a local-attention rolling
-    cache whose pad-tail writes would evict real context."""
-    return fused_ref.check_prefill_paddable(
-        lower_fused_decode(cfg, expand_masks=expand_masks))
+@dataclasses.dataclass(frozen=True)
+class PrefillSpec:
+    """Static key of one config's bucketed prefill (the trace-counter key):
+    the config and its row expansion."""
+    cfg: Any
+    n_samples: int
+
+    @property
+    def kv_dtype(self) -> str:
+        return self.cfg.kv_dtype
+
+
+def prefill_spec(cfg, *, expand_masks: bool = True) -> PrefillSpec:
+    """The bucketed prefill's key, and its support gate: raises
+    :class:`FusedPlanUnsupported` unless zero-padding a prompt to a bucket
+    is exact for every cache of ``cfg`` — global attention (GQA K/V or the
+    MLA latent) keeps slot == position, so the pad tail is disjoint from
+    real context and ``transformer.cache_trim_positions`` restores it;
+    dropless MoE routes each token on its own. Refused: non-causal and
+    M-RoPE configs, local-attention rolling caches (pad writes would evict
+    real context), recurrent state (pad tokens advance it), capacity MoE
+    (pad tokens take capacity from real ones) and int8 KV caches."""
+    if not cfg.causal:
+        raise FusedPlanUnsupported("encoder-only config has no decode step")
+    if cfg.m_rope_sections:
+        raise FusedPlanUnsupported("M-RoPE positions are not padded")
+    if cfg.kv_dtype == "int8":
+        raise FusedPlanUnsupported("int8 KV cache prefills at exact length")
+    for seg in cfg.segments():
+        for kind in seg.pattern:
+            if kind == "local_attn" and cfg.local_window:
+                raise FusedPlanUnsupported(
+                    "local-attention rolling cache cannot take padded-bucket "
+                    "prefill (pad positions would evict real context)")
+            if kind == "moe" and not cfg.moe_dropless:
+                raise FusedPlanUnsupported(
+                    "capacity-routed MoE: pad tokens would take capacity")
+            if kind not in ("attn", "local_attn", "moe"):
+                raise FusedPlanUnsupported(
+                    f"block kind {kind!r} carries state the pad would "
+                    f"advance")
+    bayes = cfg.bayesian and expand_masks
+    return PrefillSpec(cfg, cfg.mask_samples if bayes else 1)
 
 
 @functools.lru_cache(maxsize=256)
@@ -1334,9 +1371,9 @@ def _prefill_runner(cfg, expand_masks: bool, bucket: int, max_seq: int,
     capacity, backend) — stable across servers, so jit's shape cache applies
     and ``fused_trace_counts[(spec, backend, "prefill", bucket, max_seq)]``
     observes the trace count (bounded by the bucket set)."""
-    spec = prefill_fused_spec(cfg, expand_masks=expand_masks)
+    spec = prefill_spec(cfg, expand_masks=expand_masks)
     bayes = cfg.bayesian and expand_masks
-    n = cfg.mask_samples if bayes else 1
+    n = spec.n_samples
     prec = f"kv-{spec.kv_dtype}" if spec.kv_dtype else "fp32"
 
     def run(params, tokens, length):
@@ -1346,12 +1383,14 @@ def _prefill_runner(cfg, expand_masks: bool, bucket: int, max_seq: int,
         rows = tokens.shape[0]
         ids = jnp.repeat(jnp.arange(n), rows // n) if bayes else None
         ln = jnp.asarray(length, jnp.int32)
-        logits, caches = transformer.prefill(
+        logits, caches, counts = transformer.prefill(
             cfg, params, {"tokens": tokens}, max_seq=max_seq,
-            mask_ids=ids, last_index=ln - 1)
+            mask_ids=ids, last_index=ln - 1, return_counts=True)
         caches = transformer.cache_trim_positions(caches, ln)
         mean, rel = unc_lib.token_posterior(logits, n)
-        return mean, rel, caches
+        if counts is None:
+            return mean, rel, caches
+        return mean, rel, caches, counts
 
     return jax.jit(run), spec
 
@@ -1368,7 +1407,7 @@ def compile_prefill_step(cfg, bucket: int, max_seq: int, *,
     and the cache-trim boundary — so every length sharing a bucket shares
     one trace. ``backend`` is a provenance label on the trace counter (the
     prefill graph itself lowers through XLA on every tier); raises
-    :class:`FusedPlanUnsupported` via :func:`prefill_fused_spec` when
+    :class:`FusedPlanUnsupported` via :func:`prefill_spec` when
     padded-bucket prefill would not be exact."""
     if backend not in (None, "xla", "pallas-interpret", "pallas-tpu"):
         raise ValueError(f"unknown backend {backend!r}")

@@ -18,7 +18,6 @@ from repro import compat
 from repro.kernels.fused_plan import ref as _ref
 from repro.kernels.fused_plan.ref import (FusedDecodeSpec,
                                           FusedPlanUnsupported, FusedSpec,
-                                          check_prefill_paddable,
                                           param_slots)
 from repro.kernels.pad import VMEM_LIMIT
 from repro.kernels.pad import pad_to as _pad_to
@@ -30,8 +29,7 @@ _kernel = compat.import_pallas_kernel("repro.kernels.fused_plan.kernel")
 __all__ = ["fused_plan", "fused_vmem_bytes", "check_vmem",
            "FusedPlanUnsupported",
            "VMEM_MOMENTS_LIMIT", "KERNEL_BACKEND",
-           "fused_decode", "fused_decode_vmem_bytes",
-           "check_prefill_paddable"]
+           "fused_decode", "fused_decode_vmem_bytes"]
 
 #: Scoped-VMEM budget of the fused kernels (``kernels.pad.VMEM_LIMIT``). It
 #: is passed to Mosaic as ``vmem_limit_bytes``, and a kernel whose modeled

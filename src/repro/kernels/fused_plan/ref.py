@@ -25,7 +25,7 @@ import jax.numpy as jnp
 __all__ = ["FusedStep", "FusedSpec", "FusedPlanUnsupported", "param_slots",
            "act_fn", "fused_plan_ref", "fused_moments_ref",
            "FusedDecodeSpec", "decode_param_slots", "fused_decode_ref",
-           "check_prefill_paddable", "REL_UNC_EPS"]
+           "REL_UNC_EPS"]
 
 
 class FusedPlanUnsupported(NotImplementedError):
@@ -254,27 +254,6 @@ class FusedDecodeSpec:
     def n_attn(self) -> int:
         """Cache entries consumed (one per 'attn' step, in step order)."""
         return sum(s.kind == "attn" for s in self.steps)
-
-
-def check_prefill_paddable(spec: FusedDecodeSpec) -> FusedDecodeSpec:
-    """Gate for the bucketed (zero-padded length-bucket) prefill: raise
-    :class:`FusedPlanUnsupported` unless padding a prompt to a bucket is
-    *exact* for this chain.
-
-    Lowering to a decode spec already rejects the structurally unpaddable
-    families (MoE capacity routing, recurrent state, M-RoPE, non-causal);
-    the one remaining hazard is a local-attention step — its rolling cache
-    (``smax == window``, slot = pos % window) lets pad-tail writes overwrite
-    *real* trailing positions, which no post-hoc trim can undo. Global
-    attention keeps slot == position, so the pad tail is disjoint and the
-    trim (``models.transformer.cache_trim_positions``) restores the exact
-    exact-length cache."""
-    for st in spec.steps:
-        if st.kind == "attn" and st.window:
-            raise FusedPlanUnsupported(
-                "local-attention rolling cache cannot take padded-bucket "
-                "prefill (pad positions would evict real context)")
-    return spec
 
 
 def decode_param_slots(spec: FusedDecodeSpec) -> tuple[tuple[int, str], ...]:

@@ -267,7 +267,7 @@ def attention_chunked(q: jax.Array, k: jax.Array, v: jax.Array, *,
     else:
         _, outs = jax.lax.scan(body, None,
                                (jnp.arange(sq // chunk), qc))
-    return outs.transpose(1, 2, 0, 3, 4).reshape(b, h, sq, dh)
+    return outs.transpose(1, 2, 0, 3, 4).reshape(b, h, sq, v.shape[-1])
 
 
 def attention_banded(q: jax.Array, k: jax.Array, v: jax.Array, *,
@@ -335,6 +335,114 @@ def attention_decode(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
     s = jnp.where(valid[:, None, None, None, :], s, -1e30)
     p = jax.nn.softmax(s, axis=-1)
     return _grouped_combine(p, v_cache)
+
+
+# ---------------------------------------------------------------------------
+# latent attention (MLA, DeepSeek-V2/V3) — expanded prefill, absorbed decode
+# ---------------------------------------------------------------------------
+#
+# Per position one latent is cached: the RMS-normed c_kv (kv_lora_rank) and
+# the roped key part shared by all heads (qk_rope_dim). Prefill expands it to
+# per-head K/V through ``wkv_b`` and attends causally; decode keeps the heads
+# in latent space: q_nope·W_uk^T is scored against c_kv, q_rope against the
+# cached k_rope, and the latent-space output goes through W_uv. The two paths
+# are the same function in exact arithmetic and differ in rounding only.
+# RoPE pairs dimension i with i + rope/2 (rotate-half) on the projection's
+# own output: HF DeepSeek de-interleaves first, which is a fixed permutation
+# of the rope columns of ``wq`` and ``wkv_a``.
+
+
+def mla_init(key, cfg, dtype) -> Params:
+    d, h, r = cfg.d_model, cfg.n_heads, cfg.kv_lora_rank
+    nope, rope, dv = cfg.qk_nope_dim, cfg.qk_rope_dim, cfg.v_head_dim
+    kq, ka, kb, ko = jax.random.split(key, 4)
+    return {"wq": dense_init(kq, d, h * (nope + rope), dtype),
+            "wkv_a": dense_init(ka, d, r + rope, dtype),
+            "kv_norm": norm_init(r, "rmsnorm", dtype),
+            "wkv_b": dense_init(kb, r, h * (nope + dv), dtype),
+            "wo": dense_init(ko, h * dv, d, dtype,
+                             scale=1.0 / math.sqrt(h * dv))}
+
+
+def mla_project(p: Params, xn: jax.Array, cfg, cos: jax.Array,
+                sin: jax.Array):
+    """Queries and latent of xn [B,S,D] at the positions of cos/sin:
+    (q_nope [B,H,S,nope], q_rope [B,H,S,rope] roped, latent [B,S,r+rope]
+    = normed c_kv ++ roped shared k_rope)."""
+    r, nope = cfg.kv_lora_rank, cfg.qk_nope_dim
+    q = _split_heads(dense(p["wq"], xn), cfg.n_heads)
+    q_nope, q_rope = q[..., :nope], apply_rope(q[..., nope:], cos, sin)
+    kv = dense(p["wkv_a"], xn)
+    c = norm_apply(p["kv_norm"], kv[..., :r], "rmsnorm", cfg.norm_eps)
+    k_rope = apply_rope(kv[:, None, :, r:], cos, sin)[:, 0]
+    return q_nope, q_rope, jnp.concatenate([c, k_rope.astype(c.dtype)], -1)
+
+
+def mla_expand(p: Params, latent: jax.Array, cfg
+               ) -> tuple[jax.Array, jax.Array]:
+    """Per-head K [B,H,S,nope+rope] and V [B,H,S,dv] from the latent."""
+    r, nope, h = cfg.kv_lora_rank, cfg.qk_nope_dim, cfg.n_heads
+    kv = _split_heads(dense(p["wkv_b"], latent[..., :r]), h)
+    k_rope = jnp.broadcast_to(latent[:, None, :, r:],
+                              kv.shape[:-1] + (cfg.qk_rope_dim,))
+    return jnp.concatenate([kv[..., :nope], k_rope], -1), kv[..., nope:]
+
+
+def mla_decode(p: Params, q_nope: jax.Array, q_rope: jax.Array,
+               latent: jax.Array, kpos: jax.Array, pos: jax.Array, cfg
+               ) -> jax.Array:
+    """Absorbed one-token attention: q_nope [B,H,1,nope], q_rope
+    [B,H,1,rope] against the latent cache [B,Smax,r+rope] (``kpos``
+    masks as in :func:`attention_decode`) -> [B,H,1,dv]."""
+    r, h = cfg.kv_lora_rank, cfg.n_heads
+    nope, dv = cfg.qk_nope_dim, cfg.v_head_dim
+    wkv_b = p["wkv_b"]["w"].reshape(r, h, nope + dv)
+    lat = latent.astype(q_nope.dtype)
+    c, k_rope = lat[..., :r], lat[..., r:]
+    q_lat = jnp.einsum("bhqn,rhn->bhqr", q_nope, wkv_b[..., :nope])
+    s = (jnp.einsum("bhqr,bsr->bhqs", q_lat, c,
+                    preferred_element_type=jnp.float32)
+         + jnp.einsum("bhqp,bsp->bhqs", q_rope, k_rope,
+                      preferred_element_type=jnp.float32))
+    s = s / math.sqrt(nope + cfg.qk_rope_dim)
+    pos = jnp.asarray(pos, jnp.int32)
+    qpos = pos[:, None] if pos.ndim else pos
+    valid = (kpos >= 0) & (kpos <= qpos)                # [B,Smax]
+    s = jnp.where(valid[:, None, None, :], s, -1e30)
+    pr = jax.nn.softmax(s, axis=-1).astype(c.dtype)
+    o_lat = jnp.einsum("bhqs,bsr->bhqr", pr, c)
+    return jnp.einsum("bhqr,rhv->bhqv", o_lat, wkv_b[..., nope:])
+
+
+def latent_cache(batch: int, max_seq: int, width: int, dtype,
+                 as_spec: bool = False) -> Params:
+    """The MLA cache of one layer: ``latent`` [B,Smax,width] (slot ==
+    position, slot axis -2 as for K/V) and ``kpos`` [B,Smax]."""
+    if as_spec:
+        return {"latent": jax.ShapeDtypeStruct((batch, max_seq, width),
+                                               dtype),
+                "kpos": jax.ShapeDtypeStruct((batch, max_seq), jnp.int32)}
+    return {"latent": jnp.zeros((batch, max_seq, width), dtype),
+            "kpos": jnp.full((batch, max_seq), -1, jnp.int32)}
+
+
+def latent_cache_update(cache: Params, new: jax.Array, pos: jax.Array
+                        ) -> Params:
+    """Write one step's latent [B,1,width] at slot ``pos`` (scalar or per
+    row [B]); a row at pos -1 writes a slot it marks empty."""
+    b, smax, _ = cache["latent"].shape
+    pos = jnp.asarray(pos, jnp.int32)
+    slot = pos % smax
+    new = new.astype(cache["latent"].dtype)
+    if pos.ndim == 0:
+        return {"latent": jax.lax.dynamic_update_slice_in_dim(
+                    cache["latent"], new, slot, axis=1),
+                "kpos": jax.lax.dynamic_update_slice_in_dim(
+                    cache["kpos"], jnp.broadcast_to(pos, (b, 1)), slot,
+                    axis=1)}
+    bidx = jnp.arange(b)
+    return {"latent": cache["latent"].at[bidx, slot].set(new[:, 0]),
+            "kpos": cache["kpos"].at[bidx, slot].set(pos)}
 
 
 # ---------------------------------------------------------------------------

@@ -1,17 +1,38 @@
-"""Mixture-of-Experts FFN — GShard-style grouped top-k capacity routing.
+"""Mixture-of-Experts FFN — two dispatch paths over one router.
 
-Tokens are split into groups of ``moe_group_size``; within each group every
-token picks its top-k experts and is assigned a capacity slot. Dispatch and
-combine are one-hot einsums, which GSPMD turns into all-to-alls when tokens
-are data-sharded and experts model-sharded — the standard expert-parallel
-lowering on TPU. Over-capacity tokens are dropped (their FFN output is zero;
-the residual stream carries them through), matching the classic dropped-token
-MoE used by Switch/GShard and the configs assigned here.
+* **GShard capacity** (what trains: phi3.5-moe, arctic, and the smoke
+  parity tests). Tokens are split into groups of ``moe_group_size``;
+  within each group every token picks its top-k experts and is assigned a
+  capacity slot. Dispatch and combine are one-hot einsums, which GSPMD
+  turns into all-to-alls when tokens are data-sharded and experts
+  model-sharded — the standard expert-parallel lowering on TPU.
+  Over-capacity tokens are dropped (their FFN output is zero; the residual
+  stream carries them through), matching the classic dropped-token MoE
+  used by Switch/GShard and the configs assigned here.
+
+* **Dropless grouped** (``cfg.moe_dropless``; what serves: Moonlight and
+  any config that sets it, in training too). Every (token, choice) pair is
+  sorted by expert and each projection is one ``jax.lax.ragged_dot`` over
+  the sorted rows; the rows are unsorted and combined with their gate
+  weights. No capacity, no dropped token, and a row's result does not
+  depend on which other rows share the batch -- so a padded prompt or an
+  empty pool row routes but adds nothing to the real rows. It also
+  returns the per-expert pair counts of the valid rows (serving
+  telemetry).
+
+Routers: ``softmax`` (top-k of the softmax) and ``sigmoid_bias``
+(DeepSeek-V3 ``noaux_tc`` with one group: sigmoid scores in float32, a
+bias added for selection only, the chosen scores renormalised when
+``norm_topk_prob`` and scaled by ``routed_scaling``). Shared experts
+(``n_shared_experts``) are one always-on FFN of ``n_shared_experts *
+moe_d_ff`` hidden units beside the routed ones.
 
 Masksembles over expert hidden units: the mask id of each token rides the
-dispatch one-hot, so each capacity slot knows which fixed mask to apply to
-its expert's hidden layer — the paper's technique survives routing intact
-(router untouched; see DESIGN §Arch-applicability).
+dispatch (the one-hot, or the sorted pairs), so each routed row knows which
+fixed mask to apply to its expert's hidden layer — one mask set shared by
+all experts, another over the shared expert's hidden units; the paper's
+technique survives routing intact (router untouched; see DESIGN
+§Arch-applicability).
 """
 
 from __future__ import annotations
@@ -31,7 +52,7 @@ __all__ = ["moe_init", "moe_apply"]
 
 
 def moe_init(key, cfg, dtype) -> Params:
-    d, f, e = cfg.d_model, cfg.d_ff, cfg.n_experts
+    d, f, e = cfg.d_model, cfg.expert_d_ff, cfg.n_experts
     kr, kg, ku, kd, kres = jax.random.split(key, 5)
     scale = 1.0 / math.sqrt(d)
     p: Params = {
@@ -42,6 +63,12 @@ def moe_init(key, cfg, dtype) -> Params:
         "wed": (jax.random.normal(kd, (e, f, d), jnp.float32)
                 / math.sqrt(f)).astype(dtype),
     }
+    if cfg.router == "sigmoid_bias":   # e_score_correction_bias (f32)
+        p["router_bias"] = jnp.zeros((e,), jnp.float32)
+    if cfg.n_shared_experts:
+        p["shared"] = layers.ffn_init(jax.random.fold_in(key, 5), cfg,
+                                      d_ff=cfg.n_shared_experts * f,
+                                      dtype=dtype)
     if cfg.moe_dense_residual:      # arctic: dense FFN in parallel
         p["dense"] = layers.ffn_init(kres, cfg, dtype=dtype)
     if cfg.bayesian:
@@ -56,13 +83,86 @@ def _capacity(cfg, group: int) -> int:
     return max(cfg.top_k, min(group, c))
 
 
+def route(p: Params, xt: jax.Array, cfg
+          ) -> tuple[jax.Array, jax.Array, jax.Array]:
+    """Router over tokens xt [..., D]: (chosen experts [..., k], their gate
+    weights [..., k] f32, the per-expert scores the load-balancing loss
+    reads [..., E]). The sigmoid router computes its logits in float32, as
+    the published gate does; the softmax router rounds them from the
+    parameter dtype."""
+    k = cfg.top_k
+    if cfg.router == "sigmoid_bias":
+        logits = xt.astype(jnp.float32) @ \
+            p["router"]["w"].astype(jnp.float32)
+        scores = jax.nn.sigmoid(logits)
+        _, topi = jax.lax.top_k(scores + p["router_bias"], k)
+        topv = jnp.take_along_axis(scores, topi, -1)
+    else:
+        logits = layers.dense(p["router"], xt).astype(jnp.float32)
+        scores = jax.nn.softmax(logits, axis=-1)
+        topv, topi = jax.lax.top_k(scores, k)
+    if cfg.norm_topk_prob:
+        topv = topv / (topv.sum(-1, keepdims=True) + 1e-20)
+    return topi, topv * cfg.routed_scaling, scores
+
+
+def _balance_loss(topi: jax.Array, scores: jax.Array, e: int) -> jax.Array:
+    """E * sum_e f_e * P_e over tokens [T, k] / [T, E]."""
+    f_e = jnp.mean(jax.nn.one_hot(topi, e, dtype=jnp.float32).sum(-2), 0)
+    return jnp.sum(f_e * jnp.mean(scores, 0)) * e
+
+
+def _dropless(p: Params, x: jax.Array, cfg, mask_ids, valid):
+    """Sorted grouped dispatch: (y [B,S,D], aux, counts [E] int32)."""
+    b, s, d = x.shape
+    e, k = cfg.n_experts, cfg.top_k
+    xt = x.reshape(b * s, d)
+    topi, topv, scores = route(p, xt, cfg)
+    flat = topi.reshape(-1)                                     # [T*k]
+    order = jnp.argsort(flat, stable=True)
+    tok = order // k
+    sizes = jnp.bincount(flat, length=e).astype(jnp.int32)
+    xs = xt[tok]
+    act = jax.nn.silu if cfg.activation == "silu" else jax.nn.gelu
+    h = act(jax.lax.ragged_dot(xs, p["weg"], sizes)) * \
+        jax.lax.ragged_dot(xs, p["weu"], sizes)                 # [T*k, F]
+    if mask_ids is not None and "masks" in p:
+        mid = jnp.broadcast_to(mask_ids[:, None], (b, s)).reshape(-1)
+        h = h * p["masks"][mid[tok]]
+    ye = jax.lax.ragged_dot(h, p["wed"], sizes)                 # [T*k, D]
+    inv = jnp.zeros_like(order).at[order].set(
+        jnp.arange(order.shape[0], dtype=order.dtype))
+    ye = ye[inv].reshape(b * s, k, d).astype(jnp.float32)
+    y = jnp.einsum("tk,tkd->td", topv, ye).astype(x.dtype).reshape(b, s, d)
+    ok = jnp.ones((b, s), bool) if valid is None else \
+        jnp.broadcast_to(valid, (b, s))
+    counts = jnp.zeros((e,), jnp.int32).at[flat].add(
+        jnp.repeat(ok.reshape(-1), k).astype(jnp.int32))
+    return y, _balance_loss(topi, scores, e), counts
+
+
 def moe_apply(p: Params, x: jax.Array, cfg,
-              mask_ids: jax.Array | None = None) -> tuple[jax.Array, jax.Array]:
-    """x [B, S, D] -> (y [B, S, D], aux_loss scalar).
+              mask_ids: jax.Array | None = None,
+              valid: jax.Array | None = None):
+    """x [B, S, D] -> (y [B, S, D], aux_loss scalar, counts).
 
     aux_loss is the standard load-balancing loss (mean over groups of
-    E * sum_e f_e * P_e), weighted by the caller.
+    E * sum_e f_e * P_e), weighted by the caller. ``counts`` is the
+    dropless path's per-expert pair count [E] over the rows ``valid``
+    [B, S] marks (all rows where None), None on the GShard path.
     """
+    if cfg.moe_dropless:
+        y, aux, counts = _dropless(p, x, cfg, mask_ids, valid)
+    else:
+        (y, aux), counts = _gshard(p, x, cfg, mask_ids), None
+    if "shared" in p:
+        y = y + layers.ffn_apply(p["shared"], x, cfg, mask_ids=mask_ids)
+    if "dense" in p:                # arctic's parallel dense residual
+        y = y + layers.ffn_apply(p["dense"], x, cfg, mask_ids=mask_ids)
+    return y, aux.astype(jnp.float32), counts
+
+
+def _gshard(p: Params, x: jax.Array, cfg, mask_ids):
     b, s, d = x.shape
     e, k = cfg.n_experts, cfg.top_k
     tokens = b * s
@@ -75,11 +175,8 @@ def moe_apply(p: Params, x: jax.Array, cfg,
     cap = _capacity(cfg, group)
 
     xt = x.reshape(n_groups, group, d)
-    logits = layers.dense(p["router"], xt).astype(jnp.float32)  # [G,T,E]
-    probs = jax.nn.softmax(logits, axis=-1)
-
     # top-k selection; slot assignment by prefix-sum position per expert.
-    topv, topi = jax.lax.top_k(probs, k)                        # [G,T,k]
+    topi, topv, probs = route(p, xt, cfg)                       # [G,T,k]
     onehot = jax.nn.one_hot(topi, e, dtype=jnp.float32)         # [G,T,k,E]
     # position of each (token, choice) within its expert's queue
     pos = jnp.cumsum(onehot.reshape(n_groups, group * k, e), axis=1)
@@ -126,7 +223,4 @@ def moe_apply(p: Params, x: jax.Array, cfg,
     p_e = jnp.mean(probs, axis=1)
     aux = jnp.mean(jnp.sum(f_e * p_e, axis=-1)) * e
 
-    y = y.reshape(b, s, d)
-    if "dense" in p:                # arctic's parallel dense residual
-        y = y + layers.ffn_apply(p["dense"], x, cfg, mask_ids=mask_ids)
-    return y, aux.astype(jnp.float32)
+    return y.reshape(b, s, d), aux

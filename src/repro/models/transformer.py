@@ -13,6 +13,8 @@ Block kinds:
   rec        — RG-LRU recurrent block + FFN             [hybrid]
   mlstm      — xLSTM matrix-memory block                [ssm]
   slstm      — xLSTM scalar-memory block                [ssm]
+With ``cfg.kv_lora_rank`` > 0 the attention of attn and moe blocks is
+latent attention (MLA), caching one latent per position.
 
 Three entry points:
   forward(params, tokens/embeds)        — training graph (no caches)
@@ -52,7 +54,8 @@ def _block_init(kind: str, cfg: ModelConfig, key, dtype) -> Params:
         k1, k2 = jax.random.split(key)
         p: Params = {
             "norm1": layers.norm_init(d, cfg.norm, dtype),
-            "attn": layers.attn_init(k1, cfg, dtype),
+            "attn": (layers.mla_init(k1, cfg, dtype) if cfg.mla
+                     else layers.attn_init(k1, cfg, dtype)),
             "norm2": layers.norm_init(d, cfg.norm, dtype),
         }
         if kind == "moe":
@@ -108,6 +111,12 @@ def _block_cache_spec(kind: str, cfg: ModelConfig, batch: int, max_seq: int,
                       dtype, as_spec: bool):
     dh = cfg.resolved_head_dim
     mk_kv = layers.kv_cache_specs if as_spec else layers.init_kv_cache
+    if cfg.mla and kind in ("attn", "moe"):
+        if cfg.kv_dtype == "int8":
+            raise ValueError("the MLA latent cache has no int8 form")
+        return layers.latent_cache(
+            batch, max_seq, cfg.kv_lora_rank + cfg.qk_rope_dim,
+            layers.kv_store_dtype(dtype, cfg.kv_dtype), as_spec)
     if kind in ("attn", "moe"):
         return mk_kv(batch, cfg.n_kv_heads, max_seq, dh, dtype, cfg.kv_dtype)
     if kind == "local_attn":
@@ -152,10 +161,11 @@ def cache_specs(cfg: ModelConfig, batch: int, max_seq: int):
     return _cache_tree(cfg, batch, max_seq, as_spec=True)
 
 
-# Every cache leaf — KV (k/v/kpos) and recurrent state alike — is shaped
-# [reps, batch, ...]: batch rides on axis 1. The three helpers below are the
-# slot-pool contract the serving subsystem builds on (serving/server.py):
-# a pooled cache is just a cache whose batch axis is the slot-row axis.
+# Every cache leaf — KV (k/v/kpos), the MLA latent and recurrent state
+# alike — is shaped [reps, batch, ...]: batch rides on axis 1. The three
+# helpers below are the slot-pool contract the serving subsystem builds on
+# (serving/server.py): a pooled cache is just a cache whose batch axis is
+# the slot-row axis.
 
 def cache_scatter_rows(pool, fresh, rows: jax.Array):
     """Write the rows of a small cache (batch b) into a pooled cache
@@ -195,10 +205,10 @@ def cache_trim_positions(caches, length):
     The bucketed-prefill epilogue: a prompt zero-padded to a bucket writes
     (garbage) K/V for the pad tail; trimming makes the caches bitwise
     identical to an exact-length prefill's. Assumes slot == position in
-    every KV leaf (global-attention caches with ``s <= smax``, which is the
-    only layout the bucketed prefill lowering admits — rolling local-window
+    every KV and latent leaf (global-attention caches with ``s <= smax``,
+    the only layouts the bucketed prefill admits — rolling local-window
     caches and recurrent state are rejected upstream by
-    ``core.plan.prefill_fused_spec``). ``length`` may be traced."""
+    ``core.plan.prefill_spec``). ``length`` may be traced."""
     from repro import compat
     n = jnp.asarray(length, jnp.int32)
 
@@ -211,7 +221,8 @@ def cache_trim_positions(caches, length):
             # int8-cache scales: [reps, B, hkv, smax] — slot axis is last
             keep = jnp.arange(leaf.shape[-1]) < n
             return jnp.where(keep, leaf, jnp.zeros((), leaf.dtype))
-        # k/v: [reps, B, hkv, smax, dh] — slot axis is -2
+        # k/v: [reps, B, hkv, smax, dh], latent: [reps, B, smax, r] —
+        # slot axis is -2
         keep = (jnp.arange(leaf.shape[-2]) < n)[:, None]
         return jnp.where(keep, leaf, jnp.zeros((), leaf.dtype))
 
@@ -225,7 +236,7 @@ def cache_trim_positions(caches, length):
 def _rope(cfg: ModelConfig, positions: jax.Array):
     """positions [S] or [B,S] (or [3,...] for M-RoPE) -> cos/sin shaped
     [..., S, half] broadcastable against [B, H, S, dh]."""
-    dh = cfg.resolved_head_dim
+    dh = cfg.qk_rope_dim if cfg.mla else cfg.resolved_head_dim
     rot = int(dh * cfg.rope_pct)
     rot -= rot % 2
     if cfg.m_rope_sections:
@@ -250,8 +261,10 @@ def _rope(cfg: ModelConfig, positions: jax.Array):
 def _attention_sublayer(cfg: ModelConfig, p: Params, x: jax.Array, rope,
                         mode: str, kind: str, cache, pos):
     """Shared attention sub-layer for attn/local_attn/moe blocks."""
+    if cfg.mla:
+        return _mla_sublayer(cfg, p, x, rope, mode, cache, pos)
     h, hkv, dh = cfg.n_heads, cfg.n_kv_heads, cfg.resolved_head_dim
-    xn = layers.norm_apply(p["norm1"], x, cfg.norm)
+    xn = layers.norm_apply(p["norm1"], x, cfg.norm, cfg.norm_eps)
     q = layers._split_heads(layers.dense(p["attn"]["wq"], xn), h)
     k = layers._split_heads(layers.dense(p["attn"]["wk"], xn), hkv)
     v = layers._split_heads(layers.dense(p["attn"]["wv"], xn), hkv)
@@ -354,11 +367,55 @@ def _attention_sublayer(cfg: ModelConfig, p: Params, x: jax.Array, rope,
         new_cache
 
 
+def _mla_sublayer(cfg: ModelConfig, p: Params, x: jax.Array, rope,
+                  mode: str, cache, pos):
+    """Latent attention: expanded and causal over the prompt (train,
+    prefill), absorbed over the latent cache (decode). Prefill caches the
+    prompt's latents at slot == position."""
+    xn = layers.norm_apply(p["norm1"], x, cfg.norm, cfg.norm_eps)
+    a = p["attn"]
+    q_nope, q_rope, lat = layers.mla_project(a, xn, cfg, *rope)
+    new_cache = None
+    if mode == "decode":
+        new_cache = layers.latent_cache_update(cache, lat, pos)
+        attn = layers.mla_decode(a, q_nope, q_rope, new_cache["latent"],
+                                 new_cache["kpos"], pos, cfg)
+    else:
+        s = x.shape[1]
+        k, v = layers.mla_expand(a, lat, cfg)
+        q = jnp.concatenate([q_nope, q_rope], -1)
+        if s > cfg.attn_chunk and cfg.causal:
+            attn = layers.attention_chunked(q, k, v, causal=True,
+                                            chunk=cfg.attn_chunk,
+                                            scores_f32=cfg.attn_scores_f32,
+                                            unroll=cfg.analysis_unroll)
+        else:
+            attn = layers.attention_full(q, k, v, causal=cfg.causal,
+                                         scores_f32=cfg.attn_scores_f32)
+        if mode == "prefill":
+            smax = cache["latent"].shape[1] if cache is not None else s
+            if s > smax:
+                raise ValueError(f"prompt length {s} exceeds cache capacity "
+                                 f"{smax}; raise max_seq")
+            store = layers.kv_store_dtype(lat.dtype, cfg.kv_dtype)
+            slot = jnp.arange(smax, dtype=jnp.int32)
+            kpos = jnp.where(slot < s, slot, -1)
+            new_cache = {
+                "latent": jnp.pad(lat.astype(store),
+                                  ((0, 0), (0, smax - s), (0, 0))),
+                "kpos": jnp.broadcast_to(kpos[None], (x.shape[0], smax))}
+    return x + layers.dense(a["wo"], layers._merge_heads(attn)), new_cache
+
+
 def _block_apply(kind: str, cfg: ModelConfig, p: Params, x: jax.Array, *,
-                 mode: str, rope, mask_ids, cache=None, pos=None):
+                 mode: str, rope, mask_ids, cache=None, pos=None,
+                 valid=None):
     """x: [B,S,D] (train/prefill) or [B,1,D] (decode).
-    Returns (x, new_cache, aux_loss)."""
+    Returns (x, new_cache, aux_loss, counts): ``counts`` is a dropless MoE
+    block's per-expert pair count [E] over the tokens ``valid`` [B,S]
+    marks, None for every other block."""
     aux = jnp.zeros((), jnp.float32)
+    counts = None
     seqp = ("batch", "model", None) if (cfg.seq_shard and mode != "decode") \
         else None
     if kind in ("attn", "local_attn", "moe"):
@@ -366,7 +423,7 @@ def _block_apply(kind: str, cfg: ModelConfig, p: Params, x: jax.Array, *,
                                            cache, pos)
         if seqp:
             x = layers.constrain(x, seqp)
-        xn = layers.norm_apply(p["norm2"], x, cfg.norm)
+        xn = layers.norm_apply(p["norm2"], x, cfg.norm, cfg.norm_eps)
         if kind == "moe":
             if seqp and not cfg.moe_local_groups:
                 # MoE grouping crosses sequence-shard boundaries: gather the
@@ -376,16 +433,17 @@ def _block_apply(kind: str, cfg: ModelConfig, p: Params, x: jax.Array, *,
                 # With moe_local_groups the groups nest inside sequence
                 # shards instead and no gather happens (arctic iteration 3).
                 xn = layers.constrain(xn, ("batch", None, None))
-            y, aux = moe_lib.moe_apply(p["moe"], xn, cfg, mask_ids=mask_ids)
+            y, aux, counts = moe_lib.moe_apply(p["moe"], xn, cfg,
+                                               mask_ids=mask_ids, valid=valid)
         else:
             y = layers.ffn_apply(p["ffn"], xn, cfg, mask_ids=mask_ids)
         out = x + y
         if seqp:
             out = layers.constrain(out, seqp)
-        return out, new_cache, aux
+        return out, new_cache, aux, counts
 
     if kind == "rec":
-        xn = layers.norm_apply(p["norm1"], x, cfg.norm)
+        xn = layers.norm_apply(p["norm1"], x, cfg.norm, cfg.norm_eps)
         if mode == "decode":
             y, new_cache = rglru.rec_block_step(p["rec"], xn[:, 0], cache,
                                                 cfg)
@@ -395,9 +453,9 @@ def _block_apply(kind: str, cfg: ModelConfig, p: Params, x: jax.Array, *,
             if mode == "train":
                 new_cache = None
         x = x + y
-        xn2 = layers.norm_apply(p["norm2"], x, cfg.norm)
+        xn2 = layers.norm_apply(p["norm2"], x, cfg.norm, cfg.norm_eps)
         return x + layers.ffn_apply(p["ffn"], xn2, cfg, mask_ids=mask_ids), \
-            new_cache, aux
+            new_cache, aux, None
 
     if kind in ("mlstm", "slstm"):
         mod = xlstm.mlstm_block_step if kind == "mlstm" else \
@@ -411,7 +469,7 @@ def _block_apply(kind: str, cfg: ModelConfig, p: Params, x: jax.Array, *,
             y, new_cache = par(p, x, cfg, mask_ids=mask_ids)
             if mode == "train":
                 new_cache = None
-        return x + y, new_cache, aux
+        return x + y, new_cache, aux, None
 
     raise ValueError(kind)
 
@@ -430,9 +488,11 @@ def _remat(cfg: ModelConfig, fn):
 
 
 def _run_stack(cfg: ModelConfig, params: Params, x: jax.Array, *, mode: str,
-               rope, mask_ids, caches=None, pos=None):
-    """Run every segment. Returns (x, new_caches, total_aux)."""
-    new_caches = []
+               rope, mask_ids, caches=None, pos=None, valid=None):
+    """Run every segment. Returns (x, new_caches, total_aux, counts):
+    ``counts`` [n_moe, E] stacks the dropless MoE layers' per-expert pair
+    counts in layer order (None without such layers)."""
+    new_caches, counts = [], []
     total_aux = jnp.zeros((), jnp.float32)
     for si, seg in enumerate(cfg.segments()):
         seg_params = params["segments"][si]
@@ -442,35 +502,43 @@ def _run_stack(cfg: ModelConfig, params: Params, x: jax.Array, *, mode: str,
         def rep_body(carry, xs, seg=seg):
             h, aux = carry
             rp, rc = xs
-            new_rc = {}
+            new_rc, rep_counts = {}, []
             for i, kind in enumerate(seg.pattern):
                 bc = rc[f"b{i}"] if rc is not None else None
-                h, nc, a = _block_apply(kind, cfg, rp[f"b{i}"], h, mode=mode,
-                                        rope=rope, mask_ids=mask_ids,
-                                        cache=bc, pos=pos)
+                h, nc, a, cnt = _block_apply(
+                    kind, cfg, rp[f"b{i}"], h, mode=mode, rope=rope,
+                    mask_ids=mask_ids, cache=bc, pos=pos, valid=valid)
                 aux = aux + a
                 if nc is not None:
                     new_rc[f"b{i}"] = nc
-            return (h, aux), (new_rc if new_rc else None)
+                if cnt is not None:
+                    rep_counts.append(cnt)
+            return (h, aux), (new_rc if new_rc else None,
+                              jnp.stack(rep_counts) if rep_counts else None)
 
         if cfg.scan_layers and seg.reps > 1:
             body = _remat(cfg, rep_body)
-            (x, total_aux), seg_new_cache = jax.lax.scan(
+            (x, total_aux), (seg_new_cache, seg_counts) = jax.lax.scan(
                 body, (x, total_aux),
                 (seg_params, seg_cache))
         else:
             body = _remat(cfg, rep_body)
-            outs = []
+            outs, cnts = [], []
             for r in range(seg.reps):
                 rp = jax.tree.map(lambda a, r=r: a[r], seg_params)
                 rc = (jax.tree.map(lambda a, r=r: a[r], seg_cache)
                       if seg_cache is not None else None)
-                (x, total_aux), oc = body((x, total_aux), (rp, rc))
+                (x, total_aux), (oc, cnt) = body((x, total_aux), (rp, rc))
                 outs.append(oc)
+                cnts.append(cnt)
             seg_new_cache = (jax.tree.map(lambda *xs: jnp.stack(xs), *outs)
                              if want_cache and outs[0] is not None else None)
+            seg_counts = jnp.stack(cnts) if cnts[0] is not None else None
         new_caches.append(seg_new_cache if want_cache else None)
-    return x, new_caches, total_aux
+        if seg_counts is not None:          # [reps, blocks, E]
+            counts.append(seg_counts.reshape(-1, seg_counts.shape[-1]))
+    return x, new_caches, total_aux, \
+        (jnp.concatenate(counts) if counts else None)
 
 
 def _positions_default(cfg: ModelConfig, batch: int, seq: int):
@@ -530,21 +598,24 @@ def forward(cfg: ModelConfig, params: Params, batch: Params,
         mask_ids = masksembles.mask_ids_for_batch(b, cfg.mask_samples)
     pos = batch.get("positions", _positions_default(cfg, b, s))
     rope = _rope(cfg, pos)
-    x, _, aux = _run_stack(cfg, params, x, mode="train", rope=rope,
-                           mask_ids=mask_ids)
+    x, _, aux, _ = _run_stack(cfg, params, x, mode="train", rope=rope,
+                              mask_ids=mask_ids)
     if cfg.seq_shard:
         # one bf16 gather of the final hidden state instead of per-shard
         # partial logits thrash (EXPERIMENTS §Perf qwen2-vl iteration 4)
         x = layers.constrain(x, ("batch", None, None))
-    x = layers.norm_apply(params["final_norm"], x, cfg.norm)
+    x = layers.norm_apply(params["final_norm"], x, cfg.norm, cfg.norm_eps)
     return layers.lm_head(params["embed"], x), aux
 
 
 def prefill(cfg: ModelConfig, params: Params, batch: Params,
             max_seq: int | None = None,
             mask_ids: jax.Array | None = None,
-            last_index: jax.Array | None = None):
-    """Prefill: consume the prompt, return (last-token logits [B,V], caches).
+            last_index: jax.Array | None = None,
+            return_counts: bool = False):
+    """Prefill: consume the prompt, return (last-token logits [B,V], caches)
+    (and, with ``return_counts``, the dropless MoE layers' per-expert pair
+    counts [n_moe, E] over the positions up to ``last_index``, or None).
 
     max_seq sizes the KV caches (defaults to prompt length).
 
@@ -564,21 +635,28 @@ def prefill(cfg: ModelConfig, params: Params, batch: Params,
     caches = init_cache(cfg, b, max_seq)
     pos = batch.get("positions", _positions_default(cfg, b, s))
     rope = _rope(cfg, pos)
-    x, new_caches, _ = _run_stack(cfg, params, x, mode="prefill", rope=rope,
-                                  mask_ids=mask_ids, caches=caches)
+    valid = None if last_index is None else \
+        (jnp.arange(s) <= jnp.asarray(last_index, jnp.int32))[None]
+    x, new_caches, _, counts = _run_stack(
+        cfg, params, x, mode="prefill", rope=rope, mask_ids=mask_ids,
+        caches=caches, valid=valid)
     if last_index is None:
         x = x[:, -1:, :]
     else:
         x = jax.lax.dynamic_slice_in_dim(
             x, jnp.asarray(last_index, jnp.int32), 1, axis=1)
-    x = layers.norm_apply(params["final_norm"], x, cfg.norm)
-    return layers.lm_head(params["embed"], x)[:, 0], new_caches
+    x = layers.norm_apply(params["final_norm"], x, cfg.norm, cfg.norm_eps)
+    logits = layers.lm_head(params["embed"], x)[:, 0]
+    return (logits, new_caches, counts) if return_counts else \
+        (logits, new_caches)
 
 
 def decode_step(cfg: ModelConfig, params: Params, caches, tokens: jax.Array,
-                pos: jax.Array, mask_ids: jax.Array | None = None):
+                pos: jax.Array, mask_ids: jax.Array | None = None,
+                return_counts: bool = False):
     """One serving step: tokens [B,1] + caches @ pos -> (logits [B,V],
-    new caches).
+    new caches) (and, with ``return_counts``, the dropless MoE layers'
+    per-expert pair counts [n_moe, E] over the rows at pos >= 0, or None).
 
     ``pos`` is a scalar () shared by the whole batch, or a per-row [B]
     vector — the continuous-batching form where every cache row advances
@@ -595,7 +673,11 @@ def decode_step(cfg: ModelConfig, params: Params, caches, tokens: jax.Array,
         pos_arr = p[:, None] if not cfg.m_rope_sections else \
             jnp.broadcast_to(p[None, :, None], (3, b, 1))
     rope = _rope(cfg, pos_arr)
-    x, new_caches, _ = _run_stack(cfg, params, x, mode="decode", rope=rope,
-                                  mask_ids=mask_ids, caches=caches, pos=p)
-    x = layers.norm_apply(params["final_norm"], x, cfg.norm)
-    return layers.lm_head(params["embed"], x)[:, 0], new_caches
+    valid = (p >= 0)[:, None] if p.ndim else None
+    x, new_caches, _, counts = _run_stack(
+        cfg, params, x, mode="decode", rope=rope, mask_ids=mask_ids,
+        caches=caches, pos=p, valid=valid)
+    x = layers.norm_apply(params["final_norm"], x, cfg.norm, cfg.norm_eps)
+    logits = layers.lm_head(params["embed"], x)[:, 0]
+    return (logits, new_caches, counts) if return_counts else \
+        (logits, new_caches)

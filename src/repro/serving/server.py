@@ -36,11 +36,15 @@ changes. Decode positions are per-row — the continuous-batching form of
 ``transformer.decode_step``.
 
 Pool rows are computed batch-independently, so resident requests cannot
-perturb each other — with one caveat: MoE blocks route all rows through
-shared expert capacity, so per-request results are batch-composition-
-independent only when capacity is dropless (``capacity_factor >=
-n_experts / top_k``, as in the smoke configs); capacity-dropping MoE
-serving would need per-request routing isolation first.
+perturb each other — with one caveat: GShard-capacity MoE blocks route all
+rows through shared expert capacity, so per-request results are
+batch-composition-independent only when capacity cannot drop
+(``capacity_factor >= n_experts / top_k``, as in the smoke configs). The
+dropless grouped dispatch (``moe_dropless``, what Moonlight serves with)
+has no capacity: every row routes on its own. For such configs the decode
+and prefill steps also return each MoE layer's per-expert pair counts,
+which the server folds into its span attrs (``experts_hit``,
+``expert_load_max``) and the ``serving_moe_routed_pairs_total`` counter.
 """
 
 from __future__ import annotations
@@ -79,6 +83,12 @@ _FALLBACKS = obs_registry.REGISTRY.counter(
     "fused-executor demotions to the per-op path, by stage (build = no "
     "fused lowering for the config; trace = a kernel guard fired on a "
     "concrete pool shape) and key", labels=("stage", "key"))
+
+
+_ROUTED = obs_registry.REGISTRY.counter(
+    "serving_moe_routed_pairs_total",
+    "(token, expert) pairs the dropless MoE layers routed for live rows, "
+    "decode and prefill", labels=("layer",))
 
 
 def _note_fallback(stage: str, key: str) -> None:
@@ -136,13 +146,17 @@ class StepFns:
 
     ``prefill_spec`` is the bucketed prefill's static shape-key when the
     config admits padded length-bucket prefill (``core.plan.
-    prefill_fused_spec``), None when every admission takes the per-length
+    prefill_spec``), None when every admission takes the per-length
     exact path. With a spec, ``prefill`` dispatches each call to the
     smallest covering bucket (``core.plan.compile_prefill_step`` — one
     trace per bucket, counted in ``core.plan.fused_trace_counts`` under
     ``(spec, backend, "prefill", bucket, max_seq)``), zero-padding the
     prompt and passing its true length as a traced scalar; lengths no
-    bucket covers fall back to the exact path."""
+    bucket covers fall back to the exact path.
+
+    For a config with dropless MoE layers both steps return a fourth
+    value, the per-expert pair counts [n_moe, E] of the live rows (decode)
+    or the prompt's true positions (prefill)."""
     n_samples: int
     prefill: Callable
     decode: Callable
@@ -192,9 +206,10 @@ def step_fns(model: Model, expand_masks: bool = True,
     ``None`` (default) resolves to the power-of-two set per ``max_seq``
     (``core.plan.prefill_buckets``), an explicit tuple is validated loudly,
     and ``()`` disables bucketing — every admission then takes the
-    per-length exact prefill (the pre-bucketing behaviour). Configs with no
-    paddable lowering (MoE / recurrent / M-RoPE / local-attention rolling
-    caches) fall back to the exact path regardless.
+    per-length exact prefill (the pre-bucketing behaviour). Configs whose
+    caches do not pad exactly (``core.plan.prefill_spec``: capacity MoE,
+    recurrent state, M-RoPE, local-attention rolling caches) fall back to
+    the exact path regardless.
 
     The cache key is the hashable ``ModelConfig`` (plus ``expand_masks`` /
     ``fused`` / ``prefill_buckets``), never the ``Model`` instance —
@@ -225,21 +240,21 @@ def _step_fns(cfg, expand_masks: bool, fused: bool | None,
 
     def prefill_impl(params, tokens, max_seq):
         counts["prefill"] += 1
-        logits, caches = transformer.prefill(
+        logits, caches, routes = transformer.prefill(
             cfg, params, {"tokens": tokens}, max_seq=max_seq,
-            mask_ids=_mask_ids(tokens.shape[0]))
+            mask_ids=_mask_ids(tokens.shape[0]), return_counts=True)
         mean, rel = posterior(logits, n)
-        return mean, rel, caches
+        return (mean, rel, caches) + ((routes,) if routes is not None else ())
 
     exact_prefill = jax.jit(prefill_impl, static_argnames=("max_seq",))
 
     # Bucketed prefill: bounded retraces — one trace per (bucket, max_seq)
-    # instead of one per distinct prompt length. Gated through the fused
-    # decode lowering (core.plan.prefill_fused_spec); () disables.
+    # instead of one per distinct prompt length. Gated on the cache layout
+    # (core.plan.prefill_spec); () disables.
     prefill_spec = None
     if buckets is None or buckets:
         try:
-            prefill_spec = plan_lib.prefill_fused_spec(
+            prefill_spec = plan_lib.prefill_spec(
                 cfg, expand_masks=expand_masks)
         except plan_lib.FusedPlanUnsupported:
             prefill_spec = None
@@ -264,11 +279,11 @@ def _step_fns(cfg, expand_masks: bool, fused: bool | None,
 
     def decode_impl(params, caches, tokens, pos):
         counts["decode"] += 1
-        logits, caches = transformer.decode_step(
+        logits, caches, routes = transformer.decode_step(
             cfg, params, caches, tokens, pos,
-            mask_ids=_mask_ids(tokens.shape[0]))
+            mask_ids=_mask_ids(tokens.shape[0]), return_counts=True)
         mean, rel = posterior(logits, n)
-        return mean, rel, caches
+        return (mean, rel, caches) + ((routes,) if routes is not None else ())
 
     perop_decode = jax.jit(decode_impl, donate_argnums=donate)
 
@@ -569,6 +584,10 @@ class BayesianLMServer:
                               donate_argnums=_donate_argnums(0))
         self._caches = transformer.init_cache(mcfg, self.schedule.rows,
                                               cfg.max_seq)
+        # model layer index of each dropless MoE layer, in counts order
+        self._moe_layers = [i for i, kind in enumerate(
+            k for seg in mcfg.segments() for _ in range(seg.reps)
+            for k in seg.pattern) if kind == "moe"]
         if device is not None:
             self._caches = jax.device_put(self._caches, device)
         self._slots: list[int | None] = [None] * cfg.max_slots
@@ -757,21 +776,38 @@ class BayesianLMServer:
                 mesh_scope(self.mesh):
             with tr.span("serving.prefill",
                          path="exact" if bucket is None else "bucketed",
-                         bucket=bucket, length=len(ctx)):
+                         bucket=bucket, length=len(ctx)) as prefill_span:
                 xt = jnp.tile(jnp.asarray(ctx, jnp.int32)[None],
                               (self.schedule.n_masks, 1))
-                mean, rel, fresh = self.steps.prefill(
+                mean, rel, fresh, *routes = self.steps.prefill(
                     self.params, xt, max_seq=max_seq)
                 self._caches = self._scatter(
                     self._caches, fresh, self.schedule.rows_for_slot(slot))
             with tr.span("serving.sync"):
-                st.pending = int(jnp.argmax(mean[0]))
-                st.pending_unc = float(rel[0])
+                first, unc, routes = jax.device_get(
+                    (jnp.argmax(mean[0]), rel[0], routes))
+                st.pending, st.pending_unc = int(first), float(unc)
+            if routes:
+                self._note_routes(prefill_span, routes[0])
             st.status, st.slot = "running", slot
             self._slots[slot] = req_id
             if st.preempts == 0:
                 self.metrics.on_admit(req_id)
                 self.metrics.on_first_token(req_id)  # computed by prefill
+
+    def _note_routes(self, span, routes: np.ndarray) -> None:
+        """Fold one step's per-expert pair counts [n_moe, E] into ``span``:
+        ``experts_hit`` (experts with a pair, summed over the MoE layers)
+        and ``expert_load_max`` (the busiest expert's pairs over the mean
+        per expert, in the worst layer); and into the routed-pairs counter
+        by model layer."""
+        total = routes.sum(-1)
+        busy = routes.max(-1) * routes.shape[-1] / np.maximum(total, 1)
+        span.set(experts_hit=int((routes > 0).sum()),
+                 expert_load_max=float(busy.max(initial=0.0)))
+        for layer, n in zip(self._moe_layers, total):
+            if n:
+                _ROUTED.inc(float(n), layer=str(layer))
 
     def _release_slot(self, slot: int) -> None:
         """Free a slot group: clear host state and reset its cache rows
@@ -850,12 +886,13 @@ class BayesianLMServer:
                                  slots=len(lm),
                                  fused=self.steps.fused_live())
                     with mesh_scope(self.mesh):
-                        mean, rel, self._caches = self.steps.decode(
+                        mean, rel, self._caches, *routes = self.steps.decode(
                             self.params, self._caches, rows_tok, rows_pos)
                         best = jnp.argmax(mean, -1)
                 with tr.span("serving.sync"):
-                    nxt = np.asarray(best)
-                    rel = np.asarray(rel)
+                    nxt, rel, routes = jax.device_get((best, rel, routes))
+                if routes:
+                    self._note_routes(step_span, routes[0])
                 with tr.span("serving.absorb"):
                     for slot, rid in lm:
                         self._absorb(self.states[rid], int(nxt[slot]),

@@ -229,6 +229,48 @@ def test_qwen2_bucketed_prefill_compiles(qwen, one_chip):
     _check(compiled, kernel=False)
 
 
+# ---------------------------------------------------------------------------
+# moonlight-16b-a3b's first stage at published widths (5 layers: the dense
+# layer and four of 64 experts each), the longqa cell's 12 slots x 8192
+# ---------------------------------------------------------------------------
+
+
+@pytest.fixture(scope="module")
+def moonlight(one_chip):
+    cfg = registry.get_config("moonlight-16b-a3b", n_layers=5,
+                              mask_samples=4)
+    params = jax.eval_shape(build_model(cfg).init, jax.random.PRNGKey(0))
+    return cfg, _on(params, one_chip)
+
+
+def test_moonlight_decode_step_compiles(moonlight, one_chip):
+    """Absorbed latent attention over the 2.27 GB latent pool and the
+    grouped expert matmuls fit one chip beside 6.19 GB of weights. The TPU
+    compiler lowers ``jax.lax.ragged_dot`` to Mosaic kernels of its own
+    (``ragged-dot-metadata``, ``ragged-dot-none``): the program's only
+    custom calls."""
+    cfg, params = moonlight
+    rows = 12 * cfg.mask_samples
+    caches = _on(transformer.cache_specs(cfg, rows, 8192), one_chip)
+    fns = server_lib.step_fns(cfg, fused=False)
+    lowered = fns.decode.lower(params, caches,
+                               _sds((rows, 1), jnp.int32, one_chip),
+                               _sds((rows,), jnp.int32, one_chip))
+    assert len(lowered.out_info) == 4       # with the per-expert counts
+    compiled = lowered.compile()
+    _check(compiled, kernel=True)
+    assert "ragged-dot" in compiled.as_text()
+
+
+def test_moonlight_bucketed_prefill_compiles(moonlight, one_chip):
+    cfg, params = moonlight
+    step = plan_lib.compile_prefill_step(cfg, 1024, 8192)
+    compiled = step.lower(
+        params, _sds((cfg.mask_samples, 1024), jnp.int32, one_chip),
+        _sds((), jnp.int32, one_chip)).compile()
+    _check(compiled, kernel=True)       # the ragged-dot kernels, as above
+
+
 def test_fused_decode_refused_on_chip(one_chip):
     """Mosaic cannot lower the fused decode kernel's per-row KV gather, so
     the compiled tier refuses it with FusedPlanUnsupported (the server then
