@@ -1173,28 +1173,27 @@ def _decode_flat_caches(cfg, caches) -> tuple[jax.Array, ...]:
 def _decode_commit_caches(cfg, caches, knew: jax.Array, vnew: jax.Array,
                           pos: jax.Array):
     """Commit the kernel's fresh per-layer k/v into the pooled caches —
-    exactly ``layers.kv_cache_update`` per layer (same slot formula, same
+    exactly ``layers.kv_cache_update`` per layer, each written in place
+    through a layer view of its segment's pool (same slot formula, same
     written values), so the fused path's caches stay bitwise consistent
     with the per-op decode path's."""
     from repro.models import layers
     ai = 0
     out = []
     for si, seg in enumerate(cfg.segments()):
-        per_rep = []
+        pool = dict(caches[si])
         for r in range(seg.reps):
-            rep: Params = {}
             for bi, kind in enumerate(seg.pattern):
-                c = caches[si][f"b{bi}"]
-                cur = {"k": c["k"][r], "v": c["v"][r], "kpos": c["kpos"][r]}
-                # cast to the cache dtype here (the xla ref tier emits f32):
-                # a mixed-dtype scatter is deprecated and will hard-error
-                rep[f"b{bi}"] = layers.kv_cache_update(
-                    cur, knew[ai][:, :, None, :].astype(c["k"].dtype),
-                    vnew[ai][:, :, None, :].astype(c["v"].dtype),
-                    pos, cfg.local_window if kind == "local_attn" else 0)
+                # the xla ref tier emits f32: the write casts to the cache's
+                # dtype (a mixed-dtype scatter is deprecated)
+                view = layers.kv_cache_update(
+                    dict(pool[f"b{bi}"], layer=r), knew[ai][:, :, None, :],
+                    vnew[ai][:, :, None, :], pos,
+                    cfg.local_window if kind == "local_attn" else 0)
+                pool[f"b{bi}"] = {k: v for k, v in view.items()
+                                  if k != "layer"}
                 ai += 1
-            per_rep.append(rep)
-        out.append(jax.tree.map(lambda *xs: jnp.stack(xs), *per_rep))
+        out.append(pool)
     return out
 
 

@@ -165,7 +165,7 @@ def batch_shardings(mesh: Mesh, batch: Params) -> Params:
 
 
 def cache_shardings(mesh: Mesh, cache: Params) -> Params:
-    """KV caches [reps, B, Hkv, S, dh]: batch over ("pod","data"), sequence
+    """KV caches [reps, B, S, Hkv, dh]: batch over ("pod","data"), sequence
     over "model" (distributed decode softmax). Recurrent states
     [reps, B, W]: batch + width over "model". kpos replicates."""
     ba = batch_axes(mesh)
@@ -181,11 +181,11 @@ def cache_shardings(mesh: Mesh, cache: Params) -> Params:
         shape = leaf.shape
         s: list = [None] * leaf.ndim
         if name.endswith("/k") or name.endswith("/v"):
-            # [reps, B, Hkv, S, dh]
+            # [reps, B, S, Hkv, dh]
             if shape[1] % nshards == 0:
                 s[1] = bspec
-            if shape[3] % mesh.shape["model"] == 0:
-                s[3] = "model"
+            if shape[2] % mesh.shape["model"] == 0:
+                s[2] = "model"
             return P(*s)
         # recurrent states: [reps, B, ...] — batch + last dim over model
         if leaf.ndim >= 2 and shape[1] % nshards == 0:

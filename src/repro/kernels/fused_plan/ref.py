@@ -370,8 +370,8 @@ def decode_attn_ref(st: FusedStep, h: jax.Array, p: dict, cache, pos, cos,
     v = h @ p["wv"]
     if st.qkv_bias:
         q, k, v = q + p["bq"], k + p["bk"], v + p["bv"]
-    kc, vc, kpos = cache
-    smax = kc.shape[2]
+    kc, vc, kpos = cache                          # kc, vc [R, S, hkv, dh]
+    smax = kc.shape[1]
     slot = ((pos % st.window) if st.window else pos) % smax        # [R]
     valid = (kpos >= 0) & (kpos <= pos[:, None]) \
         & (jnp.arange(smax)[None, :] != slot[:, None])             # [R, S]
@@ -383,12 +383,13 @@ def decode_attn_ref(st: FusedStep, h: jax.Array, p: dict, cache, pos, cos,
         j = i // (hh // hkv)
         qi = rope_rotate(q[:, i * dh:(i + 1) * dh], cos, sin, rot)
         s_old = jnp.sum(qi[:, None, :].astype(jnp.float32)
-                        * kc[:, j].astype(jnp.float32), -1) * scale
+                        * kc[:, :, j].astype(jnp.float32), -1) * scale
         s_new = jnp.sum(qi * k_heads[j], -1).astype(jnp.float32) * scale
         s_all = jnp.concatenate(
             [jnp.where(valid, s_old, -1e30), s_new[:, None]], -1)  # [R, S+1]
         pr = jax.nn.softmax(s_all, -1)
-        oi = jnp.sum(pr[:, :smax, None] * vc[:, j].astype(jnp.float32), 1) \
+        oi = jnp.sum(pr[:, :smax, None] * vc[:, :, j].astype(jnp.float32),
+                     1) \
             + pr[:, smax:] * v[:, j * dh:(j + 1) * dh]
         outs.append(oi)
     y = jnp.concatenate(outs, -1) @ p["wo"]
@@ -432,7 +433,7 @@ def fused_decode_ref(spec: FusedDecodeSpec, x: jax.Array,
     """Oracle tier of the fused decode step.
 
     x [R, d_model] (embedded tokens), params per ``decode_param_slots``
-    order, caches the flattened ``(k [R,hkv,S,dh], v, kpos [R,S])`` triples
+    order, caches the flattened ``(k [R,S,hkv,dh], v, kpos [R,S])`` triples
     (one per 'attn' step, in step order), pos [R] (per-row decode
     positions, -1 = inactive row), cos/sin [R, rot/2] ->
     ``(mean_logp [b, V], rel_unc [b], k_new, v_new)`` with k_new/v_new

@@ -310,15 +310,17 @@ def attention_decode(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
                      kpos: jax.Array, pos: jax.Array,
                      k_scale: jax.Array | None = None,
                      v_scale: jax.Array | None = None) -> jax.Array:
-    """One-token decode: q [B,H,1,dh] vs cache [B,Hkv,Smax,dh]. ``kpos``
-    [B,Smax] holds the global position stored in each row's cache slot
-    (-1 = empty); slots with kpos > pos or kpos < 0 are masked (covers both
-    the linear cache and the rolling local-window cache). ``pos`` is a
-    scalar (whole batch at one position) or per-row [B] (continuous
-    batching: every row decodes at its own position). ``k_scale``/
-    ``v_scale`` [B,Hkv,Smax] dequantize an int8 cache at the gather
-    (per-slot symmetric scales from :func:`quantize_kv`)."""
-    dh = q.shape[-1]
+    """One-token decode: q [B,H,1,dh] vs cache [B,Smax,Hkv,dh] (position-
+    major, the layout the cache is stored in). ``kpos`` [B,Smax] holds the
+    global position stored in each row's cache slot (-1 = empty); slots
+    with kpos > pos or kpos < 0 are masked (covers both the linear cache
+    and the rolling local-window cache). ``pos`` is a scalar (whole batch
+    at one position) or per-row [B] (continuous batching: every row
+    decodes at its own position). ``k_scale``/``v_scale`` [B,Smax,Hkv]
+    dequantize an int8 cache at the gather (per-slot symmetric scales from
+    :func:`quantize_kv`)."""
+    b, h, sq, dh = q.shape
+    hkv = k_cache.shape[2]
     if k_scale is not None:
         k_cache = k_cache.astype(jnp.float32) * k_scale[..., None]
         v_cache = v_cache.astype(jnp.float32) * v_scale[..., None]
@@ -328,13 +330,16 @@ def attention_decode(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
         # otherwise the probabilities and the combine round to bf16
         k_cache = k_cache.astype(q.dtype)
         v_cache = v_cache.astype(q.dtype)
-    s = _grouped_scores(q, k_cache) / math.sqrt(dh)     # [B,Hkv,G,1,Smax]
+    qg = q.reshape(b, hkv, h // hkv, sq, dh)
+    s = jnp.einsum("bkgqd,bskd->bkgqs", qg, k_cache,
+                   preferred_element_type=jnp.float32) / math.sqrt(dh)
     pos = jnp.asarray(pos, jnp.int32)
     qpos = pos[:, None] if pos.ndim else pos
     valid = (kpos >= 0) & (kpos <= qpos)                # [B,Smax]
     s = jnp.where(valid[:, None, None, None, :], s, -1e30)
     p = jax.nn.softmax(s, axis=-1)
-    return _grouped_combine(p, v_cache)
+    out = jnp.einsum("bkgqs,bskd->bkgqd", p.astype(v_cache.dtype), v_cache)
+    return out.reshape(b, h, sq, -1)
 
 
 # ---------------------------------------------------------------------------
@@ -393,31 +398,34 @@ def mla_decode(p: Params, q_nope: jax.Array, q_rope: jax.Array,
                ) -> jax.Array:
     """Absorbed one-token attention: q_nope [B,H,1,nope], q_rope
     [B,H,1,rope] against the latent cache [B,Smax,r+rope] (``kpos``
-    masks as in :func:`attention_decode`) -> [B,H,1,dv]."""
+    masks as in :func:`attention_decode`) -> [B,H,1,dv].
+
+    Both contractions take the whole cached latent: the scores against
+    ``[q_nope·W_uk^T, q_rope]``, the combine over every latent column with
+    the rope columns dropped after it. Neither slices the cache, so no
+    copy of its c_kv part is made."""
     r, h = cfg.kv_lora_rank, cfg.n_heads
     nope, dv = cfg.qk_nope_dim, cfg.v_head_dim
     wkv_b = p["wkv_b"]["w"].reshape(r, h, nope + dv)
     lat = latent.astype(q_nope.dtype)
-    c, k_rope = lat[..., :r], lat[..., r:]
     q_lat = jnp.einsum("bhqn,rhn->bhqr", q_nope, wkv_b[..., :nope])
-    s = (jnp.einsum("bhqr,bsr->bhqs", q_lat, c,
-                    preferred_element_type=jnp.float32)
-         + jnp.einsum("bhqp,bsp->bhqs", q_rope, k_rope,
-                      preferred_element_type=jnp.float32))
+    q_all = jnp.concatenate([q_lat, q_rope.astype(q_lat.dtype)], -1)
+    s = jnp.einsum("bhqw,bsw->bhqs", q_all, lat,
+                   preferred_element_type=jnp.float32)
     s = s / math.sqrt(nope + cfg.qk_rope_dim)
     pos = jnp.asarray(pos, jnp.int32)
     qpos = pos[:, None] if pos.ndim else pos
     valid = (kpos >= 0) & (kpos <= qpos)                # [B,Smax]
     s = jnp.where(valid[:, None, None, :], s, -1e30)
-    pr = jax.nn.softmax(s, axis=-1).astype(c.dtype)
-    o_lat = jnp.einsum("bhqs,bsr->bhqr", pr, c)
+    pr = jax.nn.softmax(s, axis=-1).astype(lat.dtype)
+    o_lat = jnp.einsum("bhqs,bsw->bhqw", pr, lat)[..., :r]
     return jnp.einsum("bhqr,rhv->bhqv", o_lat, wkv_b[..., nope:])
 
 
 def latent_cache(batch: int, max_seq: int, width: int, dtype,
                  as_spec: bool = False) -> Params:
     """The MLA cache of one layer: ``latent`` [B,Smax,width] (slot ==
-    position, slot axis -2 as for K/V) and ``kpos`` [B,Smax]."""
+    position on axis 1, as for K/V) and ``kpos`` [B,Smax]."""
     if as_spec:
         return {"latent": jax.ShapeDtypeStruct((batch, max_seq, width),
                                                dtype),
@@ -429,20 +437,21 @@ def latent_cache(batch: int, max_seq: int, width: int, dtype,
 def latent_cache_update(cache: Params, new: jax.Array, pos: jax.Array
                         ) -> Params:
     """Write one step's latent [B,1,width] at slot ``pos`` (scalar or per
-    row [B]); a row at pos -1 writes a slot it marks empty."""
-    b, smax, _ = cache["latent"].shape
+    row [B]); a row at pos -1 writes a slot it marks empty. ``cache`` is
+    one layer's cache or a layer view of a stacked pool
+    (:func:`cache_write`).
+
+    The latent is written row by row. Its width (576 in DeepSeek-V3) is
+    not a whole number of 128-lane tiles, so the TPU stores the pool with
+    the slot axis minor; one batched scatter of [B, width] rows makes the
+    compiler re-lay the whole pool out around the layer loop, while a
+    write per row keeps its layout (the compiled-program test in
+    tests/test_tpu_compile.py holds this)."""
     pos = jnp.asarray(pos, jnp.int32)
-    slot = pos % smax
-    new = new.astype(cache["latent"].dtype)
-    if pos.ndim == 0:
-        return {"latent": jax.lax.dynamic_update_slice_in_dim(
-                    cache["latent"], new, slot, axis=1),
-                "kpos": jax.lax.dynamic_update_slice_in_dim(
-                    cache["kpos"], jnp.broadcast_to(pos, (b, 1)), slot,
-                    axis=1)}
-    bidx = jnp.arange(b)
-    return {"latent": cache["latent"].at[bidx, slot].set(new[:, 0]),
-            "kpos": cache["kpos"].at[bidx, slot].set(pos)}
+    slot = pos % cache["kpos"].shape[-1]
+    out = cache_write(cache, slot, {"latent": new[:, 0]}, by_row=True)
+    return cache_write(out, slot,
+                       {"kpos": jnp.broadcast_to(pos, new.shape[:1])})
 
 
 # ---------------------------------------------------------------------------
@@ -468,77 +477,96 @@ def quantize_kv(x: jax.Array) -> tuple[jax.Array, jax.Array]:
 
 def init_kv_cache(batch: int, n_kv: int, max_seq: int, dh: int, dtype,
                   kv_dtype: str = "") -> Params:
-    store = kv_store_dtype(dtype, kv_dtype)
-    out = {
-        "k": jnp.zeros((batch, n_kv, max_seq, dh), store),
-        "v": jnp.zeros((batch, n_kv, max_seq, dh), store),
-        "kpos": jnp.full((batch, max_seq), -1, jnp.int32),
-    }
-    if kv_dtype == "int8":
-        out["kscale"] = jnp.zeros((batch, n_kv, max_seq), jnp.float32)
-        out["vscale"] = jnp.zeros((batch, n_kv, max_seq), jnp.float32)
-    return out
+    """One layer's K/V cache, position-major: ``k``/``v`` [B,Smax,Hkv,dh],
+    ``kpos`` [B,Smax] and, for int8, ``kscale``/``vscale`` [B,Smax,Hkv].
+    Slot is axis 1 of every leaf, and one slot of one row is contiguous."""
+    return jax.tree.map(
+        lambda s: jnp.full(s.shape, -1 if s.dtype == jnp.int32 else 0,
+                           s.dtype),
+        kv_cache_specs(batch, n_kv, max_seq, dh, dtype, kv_dtype))
 
 
 def kv_cache_specs(batch: int, n_kv: int, max_seq: int, dh: int, dtype,
                    kv_dtype: str = "") -> Params:
     store = kv_store_dtype(dtype, kv_dtype)
     out = {
-        "k": jax.ShapeDtypeStruct((batch, n_kv, max_seq, dh), store),
-        "v": jax.ShapeDtypeStruct((batch, n_kv, max_seq, dh), store),
+        "k": jax.ShapeDtypeStruct((batch, max_seq, n_kv, dh), store),
+        "v": jax.ShapeDtypeStruct((batch, max_seq, n_kv, dh), store),
         "kpos": jax.ShapeDtypeStruct((batch, max_seq), jnp.int32),
     }
     if kv_dtype == "int8":
-        out["kscale"] = jax.ShapeDtypeStruct((batch, n_kv, max_seq),
+        out["kscale"] = jax.ShapeDtypeStruct((batch, max_seq, n_kv),
                                              jnp.float32)
-        out["vscale"] = jax.ShapeDtypeStruct((batch, n_kv, max_seq),
+        out["vscale"] = jax.ShapeDtypeStruct((batch, max_seq, n_kv),
                                              jnp.float32)
     return out
+
+
+def cache_write(cache: Params, slot: jax.Array, new: Params,
+                by_row: bool = False) -> Params:
+    """Write ``new[name]`` [B,...] at ``slot`` (scalar, or per row [B]) of
+    each named leaf of a cache, whose slot axis is the one after the
+    batch. ``cache`` is one layer's leaves [B,Smax,...], or a layer view
+    of a segment's stacked pool: its leaves [L,B,Smax,...] with
+    ``cache["layer"]`` naming the layer. A view is written at
+    [layer, row, slot] and nowhere else, so a pool carried through the
+    layer scan (or donated) is updated in place, one position per row.
+    Per-row slots take one batched scatter, or with ``by_row`` one
+    dynamic-update-slice per row. Leaves not in ``new`` pass through."""
+    layer = cache.get("layer")
+    lead = () if layer is None else (layer,)
+    out = dict(cache)
+    for name, val in new.items():
+        leaf, val = cache[name], val.astype(cache[name].dtype)
+        if slot.ndim and not by_row:
+            out[name] = leaf.at[lead + (jnp.arange(val.shape[0]), slot)
+                                ].set(val)
+            continue
+        # (first row, slot, rows): every row at the shared slot, or each row
+        # at its own
+        parts = [(0, slot, val)] if slot.ndim == 0 else \
+            [(r, slot[r], val[r:r + 1]) for r in range(val.shape[0])]
+        for row, at, rows in parts:
+            upd = jnp.expand_dims(rows, (0, 2) if lead else 1)
+            leaf = jax.lax.dynamic_update_slice(
+                leaf, upd, lead + (row, at) + (0,) * (val.ndim - 1))
+        out[name] = leaf
+    return out
+
+
+def cache_layer(cache: Params) -> Params:
+    """The leaves of one layer: a view's layer of the stacked pool, or
+    ``cache`` itself when it is one layer's."""
+    layer = cache.get("layer")
+    if layer is None:
+        return cache
+    return {k: v[layer] for k, v in cache.items() if k != "layer"}
 
 
 def kv_cache_update(cache: Params, k_new: jax.Array, v_new: jax.Array,
                     pos: jax.Array, window: int = 0) -> Params:
-    """Write one step's K/V at slot ``pos`` (or ``pos % W`` rolling).
+    """Write one step's K/V [B,Hkv,1,dh] at slot ``pos`` (or ``pos % W``
+    rolling).
 
     ``pos`` is a scalar (uniform batch — one dynamic-slice write) or a
     per-row [B] vector (continuous batching — each row writes its own slot
-    via a batched scatter). The fresh k/v are cast to the cache's storage
-    dtype *at commit* (bf16 caches write narrowed values; attention reads
-    upcast) — an int8 cache (``kscale``/``vscale`` leaves present)
-    quantizes per cached vector via :func:`quantize_kv` instead."""
-    b, _, smax, _ = cache["k"].shape
+    via a batched scatter). ``cache`` is one layer's cache or a layer view
+    of a stacked pool (:func:`cache_write`). The fresh k/v are cast to the
+    cache's storage dtype *at commit* (bf16 caches write narrowed values;
+    attention reads upcast) — an int8 cache (``kscale``/``vscale`` leaves
+    present) quantizes per cached vector via :func:`quantize_kv`
+    instead."""
+    smax = cache["kpos"].shape[-1]
     pos = jnp.asarray(pos, jnp.int32)
     slot = ((pos % window) if window else pos) % smax
-    quant = "kscale" in cache
-    if quant:
-        k_new, k_sc = quantize_kv(k_new)
-        v_new, v_sc = quantize_kv(v_new)
+    k_new, v_new = k_new[:, :, 0], v_new[:, :, 0]          # [B,Hkv,dh]
+    new = {"kpos": jnp.broadcast_to(pos, k_new.shape[:1])}
+    if "kscale" in cache:
+        new["k"], new["kscale"] = quantize_kv(k_new)
+        new["v"], new["vscale"] = quantize_kv(v_new)
     else:
-        k_new = k_new.astype(cache["k"].dtype)
-        v_new = v_new.astype(cache["v"].dtype)
-    if pos.ndim == 0:
-        k = jax.lax.dynamic_update_slice_in_dim(cache["k"], k_new, slot,
-                                                axis=2)
-        v = jax.lax.dynamic_update_slice_in_dim(cache["v"], v_new, slot,
-                                                axis=2)
-        kpos = jax.lax.dynamic_update_slice_in_dim(
-            cache["kpos"], jnp.broadcast_to(pos, (b, 1)), slot, axis=1)
-        out = {"k": k, "v": v, "kpos": kpos}
-        if quant:
-            out["kscale"] = jax.lax.dynamic_update_slice_in_dim(
-                cache["kscale"], k_sc, slot, axis=2)
-            out["vscale"] = jax.lax.dynamic_update_slice_in_dim(
-                cache["vscale"], v_sc, slot, axis=2)
-        return out
-    bidx = jnp.arange(b)
-    k = cache["k"].at[bidx, :, slot].set(k_new[:, :, 0])
-    v = cache["v"].at[bidx, :, slot].set(v_new[:, :, 0])
-    kpos = cache["kpos"].at[bidx, slot].set(pos)
-    out = {"k": k, "v": v, "kpos": kpos}
-    if quant:
-        out["kscale"] = cache["kscale"].at[bidx, :, slot].set(k_sc[:, :, 0])
-        out["vscale"] = cache["vscale"].at[bidx, :, slot].set(v_sc[:, :, 0])
-    return out
+        new["k"], new["v"] = k_new, v_new
+    return cache_write(cache, slot, new)
 
 
 # ---------------------------------------------------------------------------

@@ -200,7 +200,8 @@ def cache_reset_rows(pool, row_mask: jax.Array):
 
 def cache_trim_positions(caches, length):
     """Invalidate every cache entry at position >= ``length``: kpos to -1,
-    K/V to zero — exactly the init-cache state of those slots.
+    K/V, latents and int8 scales to zero — exactly the init-cache state of
+    those slots.
 
     The bucketed-prefill epilogue: a prompt zero-padded to a bucket writes
     (garbage) K/V for the pad tail; trimming makes the caches bitwise
@@ -208,23 +209,16 @@ def cache_trim_positions(caches, length):
     every KV and latent leaf (global-attention caches with ``s <= smax``,
     the only layouts the bucketed prefill admits — rolling local-window
     caches and recurrent state are rejected upstream by
-    ``core.plan.prefill_spec``). ``length`` may be traced."""
+    ``core.plan.prefill_spec``). Every such leaf is [reps, B, smax, ...]:
+    the slot axis is 2. ``length`` may be traced."""
     from repro import compat
     n = jnp.asarray(length, jnp.int32)
 
     def trim(path, leaf):
-        key = jax.tree_util.keystr(path)
-        if "kpos" in key:
-            keep = jnp.arange(leaf.shape[-1]) < n          # [smax]
-            return jnp.where(keep, leaf, -1)
-        if "kscale" in key or "vscale" in key:
-            # int8-cache scales: [reps, B, hkv, smax] — slot axis is last
-            keep = jnp.arange(leaf.shape[-1]) < n
-            return jnp.where(keep, leaf, jnp.zeros((), leaf.dtype))
-        # k/v: [reps, B, hkv, smax, dh], latent: [reps, B, smax, r] —
-        # slot axis is -2
-        keep = (jnp.arange(leaf.shape[-2]) < n)[:, None]
-        return jnp.where(keep, leaf, jnp.zeros((), leaf.dtype))
+        fill = -1 if "kpos" in jax.tree_util.keystr(path) else 0
+        keep = (jnp.arange(leaf.shape[2]) < n).reshape(
+            (-1,) + (1,) * (leaf.ndim - 3))
+        return jnp.where(keep, leaf, jnp.asarray(fill, leaf.dtype))
 
     return compat.tree_map_with_path(trim, caches)
 
@@ -302,10 +296,9 @@ def _attention_sublayer(cfg: ModelConfig, p: Params, x: jax.Array, rope,
     new_cache = None
     if mode == "decode":
         new_cache = layers.kv_cache_update(cache, k, v, pos, window)
-        attn = layers.attention_decode(q, new_cache["k"], new_cache["v"],
-                                       new_cache["kpos"], pos,
-                                       new_cache.get("kscale"),
-                                       new_cache.get("vscale"))
+        kv = layers.cache_layer(new_cache)
+        attn = layers.attention_decode(q, kv["k"], kv["v"], kv["kpos"], pos,
+                                       kv.get("kscale"), kv.get("vscale"))
     else:
         s = x.shape[1]
         # s == window takes the full path below; attention_banded's own
@@ -333,7 +326,7 @@ def _attention_sublayer(cfg: ModelConfig, p: Params, x: jax.Array, rope,
             # layout. This replaces a linear-pad / rolling branch pair that
             # split at s >= window while the attention path split at
             # s > window — the two boundaries now cannot drift apart.
-            smax = cache["k"].shape[2] if cache is not None else s
+            smax = cache["kpos"].shape[-1] if cache is not None else s
             if s > smax and (not window or smax < window):
                 # Truncating to the last smax positions is only legitimate
                 # when every dropped position is already outside the
@@ -346,8 +339,10 @@ def _attention_sublayer(cfg: ModelConfig, p: Params, x: jax.Array, rope,
             keep = min(s, smax)
             kept_pos = jnp.arange(s - keep, s, dtype=jnp.int32)
             slots = kept_pos % smax
-            shp = (x.shape[0], k.shape[1], smax, k.shape[-1])
-            kk, vk = k[:, :, -keep:], v[:, :, -keep:]
+            shp = (x.shape[0], smax, k.shape[1], k.shape[-1])
+            # position-major, the layout decode reads: [B, keep, Hkv, dh]
+            kk = k[:, :, -keep:].swapaxes(1, 2)
+            vk = v[:, :, -keep:].swapaxes(1, 2)
             store = layers.kv_store_dtype(k.dtype, cfg.kv_dtype)
             new_cache = {}
             if cfg.kv_dtype == "int8":
@@ -355,11 +350,11 @@ def _attention_sublayer(cfg: ModelConfig, p: Params, x: jax.Array, rope,
                 vk, v_sc = layers.quantize_kv(vk)
                 sshp = shp[:-1]
                 new_cache["kscale"] = jnp.zeros(
-                    sshp, jnp.float32).at[:, :, slots].set(k_sc)
+                    sshp, jnp.float32).at[:, slots].set(k_sc)
                 new_cache["vscale"] = jnp.zeros(
-                    sshp, jnp.float32).at[:, :, slots].set(v_sc)
-            ks = jnp.zeros(shp, store).at[:, :, slots].set(kk.astype(store))
-            vs = jnp.zeros(shp, store).at[:, :, slots].set(vk.astype(store))
+                    sshp, jnp.float32).at[:, slots].set(v_sc)
+            ks = jnp.zeros(shp, store).at[:, slots].set(kk.astype(store))
+            vs = jnp.zeros(shp, store).at[:, slots].set(vk.astype(store))
             kpos = jnp.full((smax,), -1, jnp.int32).at[slots].set(kept_pos)
             kpos = jnp.broadcast_to(kpos[None], (x.shape[0], smax))
             new_cache.update(k=ks, v=vs, kpos=kpos)
@@ -378,8 +373,9 @@ def _mla_sublayer(cfg: ModelConfig, p: Params, x: jax.Array, rope,
     new_cache = None
     if mode == "decode":
         new_cache = layers.latent_cache_update(cache, lat, pos)
-        attn = layers.mla_decode(a, q_nope, q_rope, new_cache["latent"],
-                                 new_cache["kpos"], pos, cfg)
+        lc = layers.cache_layer(new_cache)
+        attn = layers.mla_decode(a, q_nope, q_rope, lc["latent"], lc["kpos"],
+                                 pos, cfg)
     else:
         s = x.shape[1]
         k, v = layers.mla_expand(a, lat, cfg)
@@ -393,7 +389,7 @@ def _mla_sublayer(cfg: ModelConfig, p: Params, x: jax.Array, rope,
             attn = layers.attention_full(q, k, v, causal=cfg.causal,
                                          scores_f32=cfg.attn_scores_f32)
         if mode == "prefill":
-            smax = cache["latent"].shape[1] if cache is not None else s
+            smax = cache["kpos"].shape[-1] if cache is not None else s
             if s > smax:
                 raise ValueError(f"prompt length {s} exceeds cache capacity "
                                  f"{smax}; raise max_seq")
@@ -407,6 +403,10 @@ def _mla_sublayer(cfg: ModelConfig, p: Params, x: jax.Array, rope,
     return x + layers.dense(a["wo"], layers._merge_heads(attn)), new_cache
 
 
+# block kinds whose cache holds one entry per position (K/V or a latent)
+_POSITION_KINDS = ("attn", "local_attn", "moe")
+
+
 def _block_apply(kind: str, cfg: ModelConfig, p: Params, x: jax.Array, *,
                  mode: str, rope, mask_ids, cache=None, pos=None,
                  valid=None):
@@ -418,7 +418,7 @@ def _block_apply(kind: str, cfg: ModelConfig, p: Params, x: jax.Array, *,
     counts = None
     seqp = ("batch", "model", None) if (cfg.seq_shard and mode != "decode") \
         else None
-    if kind in ("attn", "local_attn", "moe"):
+    if kind in _POSITION_KINDS:
         x, new_cache = _attention_sublayer(cfg, p, x, rope, mode, kind,
                                            cache, pos)
         if seqp:
@@ -491,54 +491,96 @@ def _run_stack(cfg: ModelConfig, params: Params, x: jax.Array, *, mode: str,
                rope, mask_ids, caches=None, pos=None, valid=None):
     """Run every segment. Returns (x, new_caches, total_aux, counts):
     ``counts`` [n_moe, E] stacks the dropless MoE layers' per-expert pair
-    counts in layer order (None without such layers)."""
+    counts in layer order (None without such layers).
+
+    In decode mode each segment's stacked cache rides in the layer loop's
+    carry and is written in place (:func:`_decode_block`); train and
+    prefill take per-layer caches in and build new ones out."""
     new_caches, counts = [], []
     total_aux = jnp.zeros((), jnp.float32)
+    decode = mode == "decode"
     for si, seg in enumerate(cfg.segments()):
         seg_params = params["segments"][si]
         seg_cache = caches[si] if caches is not None else None
-        want_cache = mode != "train"
 
         def rep_body(carry, xs, seg=seg):
-            h, aux = carry
+            h, aux, pool = carry
             rp, rc = xs
+            pool = dict(pool)
             new_rc, rep_counts = {}, []
             for i, kind in enumerate(seg.pattern):
-                bc = rc[f"b{i}"] if rc is not None else None
-                h, nc, a, cnt = _block_apply(
-                    kind, cfg, rp[f"b{i}"], h, mode=mode, rope=rope,
-                    mask_ids=mask_ids, cache=bc, pos=pos, valid=valid)
+                if decode:
+                    h, pool[f"b{i}"], a, cnt = _decode_block(
+                        kind, cfg, rp[f"b{i}"], h, pool[f"b{i}"], rc,
+                        rope=rope, mask_ids=mask_ids, pos=pos, valid=valid)
+                else:
+                    h, nc, a, cnt = _block_apply(
+                        kind, cfg, rp[f"b{i}"], h, mode=mode, rope=rope,
+                        mask_ids=mask_ids,
+                        cache=rc[f"b{i}"] if rc is not None else None,
+                        valid=valid)
+                    if nc is not None:
+                        new_rc[f"b{i}"] = nc
                 aux = aux + a
-                if nc is not None:
-                    new_rc[f"b{i}"] = nc
                 if cnt is not None:
                     rep_counts.append(cnt)
-            return (h, aux), (new_rc if new_rc else None,
-                              jnp.stack(rep_counts) if rep_counts else None)
+            return (h, aux, pool), (new_rc if new_rc else None,
+                                    jnp.stack(rep_counts) if rep_counts
+                                    else None)
 
+        body = _remat(cfg, rep_body)
+        # decode: the carry holds the pool and xs the layer index; else xs
+        # holds each layer's cache and the new ones come out as ys
+        pool = dict(seg_cache) if decode else {}
         if cfg.scan_layers and seg.reps > 1:
-            body = _remat(cfg, rep_body)
-            (x, total_aux), (seg_new_cache, seg_counts) = jax.lax.scan(
-                body, (x, total_aux),
-                (seg_params, seg_cache))
+            xs = jnp.arange(seg.reps) if decode else seg_cache
+            (x, total_aux, pool), (seg_new_cache, seg_counts) = \
+                jax.lax.scan(body, (x, total_aux, pool), (seg_params, xs))
         else:
-            body = _remat(cfg, rep_body)
             outs, cnts = [], []
             for r in range(seg.reps):
                 rp = jax.tree.map(lambda a, r=r: a[r], seg_params)
-                rc = (jax.tree.map(lambda a, r=r: a[r], seg_cache)
-                      if seg_cache is not None else None)
-                (x, total_aux), (oc, cnt) = body((x, total_aux), (rp, rc))
+                rc = r if decode else (
+                    jax.tree.map(lambda a, r=r: a[r], seg_cache)
+                    if seg_cache is not None else None)
+                (x, total_aux, pool), (oc, cnt) = body(
+                    (x, total_aux, pool), (rp, rc))
                 outs.append(oc)
                 cnts.append(cnt)
             seg_new_cache = (jax.tree.map(lambda *xs: jnp.stack(xs), *outs)
-                             if want_cache and outs[0] is not None else None)
+                             if outs[0] is not None else None)
             seg_counts = jnp.stack(cnts) if cnts[0] is not None else None
-        new_caches.append(seg_new_cache if want_cache else None)
+        new_caches.append(pool if decode else
+                          seg_new_cache if mode != "train" else None)
         if seg_counts is not None:          # [reps, blocks, E]
             counts.append(seg_counts.reshape(-1, seg_counts.shape[-1]))
     return x, new_caches, total_aux, \
         (jnp.concatenate(counts) if counts else None)
+
+
+def _decode_block(kind: str, cfg: ModelConfig, p: Params, x: jax.Array,
+                  pool, layer, *, rope, mask_ids, pos, valid):
+    """One block of the decode step against its segment's stacked pool
+    ``pool`` [reps, ...] at layer ``layer`` (traced in the layer scan, an
+    int when unrolled). Returns (x, pool, aux, counts).
+
+    A block that caches per position (K/V, the MLA latent, kpos and the
+    int8 scales) gets a layer view of the pool and writes its one new
+    position per row in place (``layers.cache_write``); its attention
+    reads that layer of the updated pool. Recurrent and xLSTM state is
+    whole-state by nature and small: it is read and written per layer."""
+    if kind in _POSITION_KINDS:
+        x, view, aux, counts = _block_apply(
+            kind, cfg, p, x, mode="decode", rope=rope, mask_ids=mask_ids,
+            cache=dict(pool, layer=layer), pos=pos, valid=valid)
+        return x, {k: v for k, v in view.items() if k != "layer"}, aux, \
+            counts
+    x, state, aux, counts = _block_apply(
+        kind, cfg, p, x, mode="decode", rope=rope, mask_ids=mask_ids,
+        cache=jax.tree.map(lambda a: a[layer], pool), pos=pos, valid=valid)
+    pool = jax.tree.map(lambda a, n: a.at[layer].set(n.astype(a.dtype)),
+                        pool, state)
+    return x, pool, aux, counts
 
 
 def _positions_default(cfg: ModelConfig, batch: int, seq: int):

@@ -76,9 +76,10 @@ def test_absorbed_decode_equals_expanded_attention(smoke):
     kpos = jnp.where(jnp.arange(smax)[None] <= pos[:, None],
                      jnp.arange(smax)[None], -1)
     got = layers.mla_decode(a, q_nope, q_rope, lat, kpos, pos, cfg)
-    k, v = layers.mla_expand(a, lat, cfg)
+    k, v = layers.mla_expand(a, lat, cfg)           # [B,H,S,*]
     want = layers.attention_decode(jnp.concatenate([q_nope, q_rope], -1),
-                                   k, v, kpos, pos)
+                                   k.swapaxes(1, 2), v.swapaxes(1, 2),
+                                   kpos, pos)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-5, atol=1e-5)
 

@@ -370,9 +370,9 @@ def test_cache_trim_clears_scale_leaves(qsmoke):
     trimmed = transformer.cache_trim_positions(caches, jnp.int32(3))
     for path, leaf in jax.tree_util.tree_leaves_with_path(trimmed):
         nm = str(path)
-        if "kscale" in nm or "vscale" in nm:
-            assert np.all(np.asarray(leaf)[..., 3:] == 0), nm
-            assert np.any(np.asarray(leaf)[..., :3] != 0), nm
+        if "kscale" in nm or "vscale" in nm:       # [reps, B, smax, hkv]
+            assert np.all(np.asarray(leaf)[:, :, 3:] == 0), nm
+            assert np.any(np.asarray(leaf)[:, :, :3] != 0), nm
 
 
 def test_decode_stage_traffic_kv_dtype_pricing(qsmoke):

@@ -14,6 +14,7 @@ this file.
 
 import dataclasses
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -227,6 +228,131 @@ def test_qwen2_bucketed_prefill_compiles(qwen, one_chip):
         params, _sds((cfg.mask_samples, 256), jnp.int32, one_chip),
         _sds((), jnp.int32, one_chip)).compile()
     _check(compiled, kernel=False)
+
+
+# ---------------------------------------------------------------------------
+# the decode step writes the cells' pools in place
+# ---------------------------------------------------------------------------
+
+_MOVES = {"copy", "copy-start", "copy-done", "transpose"}
+_VIEWS = {"parameter", "get-tuple-element", "tuple", "bitcast", "while"}
+
+
+def _hlo_computations(text):
+    """{computation: {instruction: (shape, opcode, operands)}} of an HLO
+    module's text, and the names of the fused computations."""
+    comps, cur = {}, None
+    for line in text.splitlines():
+        head = re.match(r"^(?:ENTRY )?%([\w.\-]+) .*\{\s*$", line)
+        if head:
+            cur = comps.setdefault(head.group(1), {})
+            continue
+        m = re.match(r"\s*(?:ROOT )?%([\w.\-]+) = \w+\[([\d,]*)\]"
+                     r"(?:\{[^}]*\})? ([\w\-]+)\((.*)", line)
+        if cur is None or not m:
+            continue
+        name, dims, op, rest = m.groups()
+        depth, end = 1, 0
+        while depth and end < len(rest):
+            depth += {"(": 1, ")": -1}.get(rest[end], 0)
+            end += 1
+        shape = tuple(int(d) for d in dims.split(",") if d)
+        callee = re.search(r"calls=%([\w.\-]+)", rest)
+        cur[name] = (shape, op, re.findall(r"%([\w.\-]+)", rest[:end]),
+                     callee.group(1) if callee else None,
+                     "ROOT" in line.split("=")[0])
+    fused = {c[3] for comp in comps.values() for c in comp.values()
+             if c[1] == "fusion" and c[3]}
+    return comps, fused
+
+
+def _pool_traffic(compiled, caches):
+    """The instructions outside fused computations — in the entry and the
+    layer loop — that output a whole pool leaf or one layer's slice of it,
+    by what they do: ``write`` (a scatter, or a dynamic-update-slice of one
+    position per row, in place), ``slice`` (a fusion that materialises a
+    layer's slice) and ``rewrite`` (everything else that moves such a
+    buffer: a copy, a transpose, a whole-slice update). The leaves looked
+    for are those that hold K/V or latents; kpos is a mask's size."""
+    leaves = [a for path, a in jax.tree_util.tree_leaves_with_path(caches)
+              if "kpos" not in jax.tree_util.keystr(path)]
+    stacks = {tuple(a.shape) for a in leaves}
+    slices = {s[1:] for s in stacks} | {(1,) + s[1:] for s in stacks}
+    # one position of every row of a leaf [L, B, S, ...]: B x the rest
+    position = max(int(np.prod(s[1:2] + s[3:])) for s in stacks)
+    comps, fused = _hlo_computations(compiled.as_text())
+    out = {"write": [], "slice": [], "rewrite": []}
+
+    def update_is_position(comp, operands):
+        upd = comp.get(operands[1])
+        return upd is not None and np.prod(upd[0]) <= position
+
+    for cname, comp in comps.items():
+        if cname in fused:
+            continue
+        for name, (shape, op, operands, callee, _) in comp.items():
+            if (shape not in stacks and shape not in slices) or \
+                    op in _VIEWS:
+                continue
+            kind = "rewrite"
+            if op == "scatter":
+                kind = "write"
+            elif op == "dynamic-update-slice":
+                if update_is_position(comp, operands):
+                    kind = "write"
+            elif op == "fusion" and callee in comps:
+                body = comps[callee]
+                _, rop, rops, _, _ = next(v for v in body.values() if v[4])
+                if rop == "scatter" or (rop == "dynamic-update-slice"
+                                        and update_is_position(body, rops)):
+                    kind = "write"
+                elif rop == "dynamic-slice":
+                    kind = "slice"
+            out[kind].append(f"{name} {op} {list(shape)}")
+    return out
+
+
+def _compile_pool_decode(cfg, params, rows, smax, one_chip):
+    """The per-op decode at a cell's pool, its caches donated as on the
+    chip (``server._donate_argnums`` gives none on the CPU backend)."""
+    caches = _on(transformer.cache_specs(cfg, rows, smax), one_chip)
+    decode = jax.jit(server_lib.step_fns(cfg, fused=False).decode,
+                     donate_argnums=(1,))
+    compiled = decode.lower(params, caches,
+                            _sds((rows, 1), jnp.int32, one_chip),
+                            _sds((rows,), jnp.int32, one_chip)).compile()
+    return compiled, _pool_traffic(compiled, caches)
+
+
+def test_chat_decode_writes_its_pool_in_place(qwen, one_chip):
+    """qwen2-1.5b.chat's pool (24 slots x 4 masks x 2048, 5.64 GB): one
+    position per row is scattered into the pool the layer loop carries,
+    and no copy of the pool or rewrite of a layer's slice is left. The
+    compiler still materialises the layer's K and V slices for the
+    attention (two per layer, outside HBM temporaries): their count is
+    held so that more cannot come back unseen."""
+    cfg, params = qwen
+    compiled, ops = _compile_pool_decode(cfg, params, 24 * cfg.mask_samples,
+                                         2048, one_chip)
+    assert ops["rewrite"] == [], ops
+    assert len(ops["write"]) == 2, ops          # K and V
+    assert len(ops["slice"]) == 2, ops
+    assert compiled.memory_analysis().temp_size_in_bytes < 1e9
+
+
+def test_longqa_decode_writes_its_pool_in_place(moonlight, one_chip):
+    """moonlight-16b-a3b.longqa's latent pool (12 slots x 4 masks x 8192,
+    5 layers, 2.27 GB): each row's latent is written in place at its one
+    position, with no copy of the pool around the layer loop, and the
+    absorbed attention reads the layer from the pool inside its own
+    fusion."""
+    cfg, params = moonlight
+    compiled, ops = _compile_pool_decode(cfg, params, 12 * cfg.mask_samples,
+                                         8192, one_chip)
+    assert ops["rewrite"] == [], ops
+    assert ops["write"], ops
+    assert ops["slice"] == [], ops
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.5e9
 
 
 # ---------------------------------------------------------------------------
